@@ -19,6 +19,7 @@ from benchmarks import (bench_adders, bench_autotune, bench_carry_tables,
                         bench_cla_vs_lut, bench_collectives, bench_lemma3,
                         bench_moa_kernels, bench_neuron, bench_serve,
                         bench_transition)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "carry_tables": (bench_carry_tables, "Tables 1a/1b/1c + 2"),
@@ -70,6 +71,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=sorted(BENCHES))
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     failures = []
     for name in names:

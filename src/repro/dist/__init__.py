@@ -4,10 +4,9 @@
                in-register, in-VMEM (Pallas) and cross-device tiers
 - collectives: factor_radix4 / make_tree_mesh / tree_psum /
                tree_reduce_scatter_gather
-- compat:      jax.shard_map / pvary / get_abstract_mesh across jax versions
 
 Only ``plan`` (no direct jax dependency) is imported eagerly;
-``collectives``/``compat`` — which build jax machinery at import — load on
-their first ``from repro.dist import ...``.
+``collectives`` — which builds jax machinery at import — loads on
+its first ``from repro.dist import ...``.
 """
 from repro.dist import plan  # noqa: F401

@@ -24,17 +24,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import carry as carry_theory
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    _COMPILER_PARAMS = _params_cls(
-        dimension_semantics=("parallel",))
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel",))
 
 __all__ = ["bitplane_add_kernel", "bitplane_add_pallas"]
 
@@ -99,7 +94,8 @@ def bitplane_add_pallas(x: jnp.ndarray, *, m_bits: int, bb: int = 1024,
         grid=grid,
         in_specs=[pl.BlockSpec((n, bb), lambda i: (0, i))],
         out_specs=pl.BlockSpec((bb,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((batch,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((batch,), jnp.int32,
+                                       vma=jax.typeof(x).vma),
         compiler_params=_COMPILER_PARAMS if not interpret else None,
         interpret=interpret,
     )(x.astype(jnp.int32))

@@ -26,17 +26,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU compiler params are versioned; fall back gracefully.
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    _COMPILER_PARAMS = _params_cls(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
-    _VMEM = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 __all__ = ["flash_attention_kernel", "flash_attention_pallas"]
 
@@ -128,13 +121,14 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     v2 = v.transpose(0, 2, 1, 3).reshape(b * hkv, s, hdp)
 
     grid = (b * hkv, rep * s // block_q, s // block_k)
+    # under jax.shard_map the output varies over the axes q, k, v do
+    vma = jax.typeof(q).vma | jax.typeof(k).vma | jax.typeof(v).vma
     kernel = functools.partial(
         flash_attention_kernel, scale=scale, block_q=block_q,
         block_k=block_k, seq=s, causal=causal)
-    scratch = ([_VMEM((block_q,), jnp.float32),
-                _VMEM((block_q,), jnp.float32),
-                _VMEM((block_q, hdp), jnp.float32)]
-               if _VMEM is not None else [])
+    scratch = [pltpu.VMEM((block_q,), jnp.float32),
+               pltpu.VMEM((block_q,), jnp.float32),
+               pltpu.VMEM((block_q, hdp), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -144,7 +138,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pl.BlockSpec((1, block_k, hdp), lambda i, j, kk: (i, kk, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hdp), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct(q2.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q2.shape, q.dtype, vma=vma),
         scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS if not interpret else None,

@@ -22,17 +22,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import repro.dist.plan as dist_plan
 
-try:  # TPU compiler params are versioned; fall back gracefully.
-    from jax.experimental.pallas import tpu as pltpu
-    _params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    _COMPILER_PARAMS = _params_cls(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 __all__ = ["moa_reduce_kernel", "moa_reduce_pallas", "radix4_tree_sum"]
 
@@ -122,7 +117,10 @@ def moa_reduce_pallas(x: jnp.ndarray, *, bm: int = 256, bn: int = 256,
         grid=grid,
         in_specs=[pl.BlockSpec((bk, bm, bn), lambda i, j, k: (k, i, j))],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows, cols), acc_dtype),
+        # under jax.shard_map (the sharded serve dispatches) the sum varies
+        # over the mesh axes its operands vary over
+        out_shape=jax.ShapeDtypeStruct((rows, cols), acc_dtype,
+                                       vma=jax.typeof(x).vma),
         compiler_params=_COMPILER_PARAMS if not interpret else None,
         interpret=interpret,
     )(x)

@@ -20,17 +20,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.accum import plan_dot_accumulation
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    _COMPILER_PARAMS = _params_cls(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 __all__ = ["quant_matmul_kernel", "quant_matmul_pallas"]
 
@@ -85,7 +80,8 @@ def quant_matmul_pallas(x: jnp.ndarray, w: jnp.ndarray, *, bm: int = 256,
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct(
+            (m, n), jnp.int32, vma=jax.typeof(x).vma | jax.typeof(w).vma),
         compiler_params=_COMPILER_PARAMS if not interpret else None,
         interpret=interpret,
     )(x.astype(jnp.int8), w.astype(jnp.int8))

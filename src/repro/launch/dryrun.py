@@ -1,7 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # The dry-run is the ONLY entry point that sees 512 placeholder devices.
+# It is a fake-mesh tool, so it stays on the CPU: on a TPU host this
+# process (and each --isolate child, which inherits the environment) then
+# never takes the chip.
 
 import argparse
 import json

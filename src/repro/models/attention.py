@@ -17,9 +17,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist import compat
 from repro.models.common import (ParamSpec, apply_rope, constrain,
-                                 rope_angles, shardmap_mesh)
+                                 manual_axis_sizes, rope_angles,
+                                 shardmap_mesh)
 from repro.models.common import scan as mscan
 
 __all__ = ["gqa_param_specs", "gqa_train", "gqa_decode", "gqa_decode_paged",
@@ -211,11 +211,11 @@ def tp_head_pad(cfg: ModelConfig) -> int:
     if mesh is not None and "model" in mesh.shape:
         tp = mesh.shape["model"]
     else:
-        am = compat.get_abstract_mesh()
-        if am is not None and not am.empty and "model" in am.shape:
+        am = jax.sharding.get_abstract_mesh()
+        if not am.empty and "model" in am.shape:
             tp = dict(am.shape).get("model", 1)
         else:
-            tp = compat.manual_axis_sizes().get("model", 1)
+            tp = manual_axis_sizes().get("model", 1)
     if tp <= 1 or cfg.n_heads % tp == 0:
         return 0
     # pad WITHIN each kv group (rep -> rep_pad) so the q-head -> kv-head
@@ -294,21 +294,15 @@ def gqa_train(x: jnp.ndarray, p: dict, cfg: ModelConfig,
         out = constrain(out, ("batch", "seq_sp", None))
         return out @ p["wo"].astype(x.dtype)
 
-    # Padding only buys anything through the head-sharding constraint, and
-    # old GSPMD miscompiles that constraint on the padded axis (wrong
-    # values, not just a reshard) — so skip both together there and fall
-    # back to exact replicated attention instead of paying padded FLOPs.
-    raw_pad = tp_head_pad(cfg)
-    pad = 0 if compat.OLD_PARTITIONER else raw_pad
+    pad = tp_head_pad(cfg)
     hq = cfg.n_heads + pad
     q = _pad_heads(q, pad, cfg.n_kv_heads)
     n_rep = hq // cfg.n_kv_heads
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    if not (compat.OLD_PARTITIONER and raw_pad):
-        q = constrain(q, ("batch", None, "q_heads", None))
-        k = constrain(k, ("batch", None, "q_heads", None))
-        v = constrain(v, ("batch", None, "q_heads", None))
+    q = constrain(q, ("batch", None, "q_heads", None))
+    k = constrain(k, ("batch", None, "q_heads", None))
+    v = constrain(v, ("batch", None, "q_heads", None))
 
     chunk = min(cfg.attn_chunk, s)
     if s % chunk:
@@ -392,7 +386,7 @@ def gqa_decode_splitk(x: jnp.ndarray, p: dict, cfg: ModelConfig,
     batch_spec = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes
                                                    else None)
     cache_spec = P(batch_spec, "model", None, None)
-    out, cache_k, cache_v = compat.shard_map(
+    out, cache_k, cache_v = jax.shard_map(
         local, mesh=shardmap_mesh(mesh),
         axis_names=frozenset(mesh.axis_names),
         in_specs=(P(batch_spec), P(batch_spec), P(batch_spec),

@@ -23,14 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.dist import compat
+from jax._src import mesh as _jax_mesh
 
 __all__ = [
     "ParamSpec", "init_params", "shape_structs", "make_shardings",
     "logical_to_pspec", "constrain", "DEFAULT_RULES",
     "rms_norm", "rope_angles", "apply_rope", "cross_entropy_loss",
-    "param_count", "scan", "unrolled_scans",
+    "param_count", "scan", "unrolled_scans", "manual_axis_sizes",
+    "match_vma",
 ]
 
 # ---------------------------------------------------------------------------
@@ -103,9 +103,17 @@ def _init_leaf(spec: ParamSpec, key) -> jnp.ndarray:
             ).astype(spec.dtype)
 
 
-def init_params(specs, key) -> Any:
-    """Initialize a full param pytree from a ParamSpec tree."""
+def init_params(specs, key, dtype=None) -> Any:
+    """Initialize a full param pytree from a ParamSpec tree.
+
+    ``dtype`` stores every floating leaf in that type instead of its
+    spec's (the serve path keeps weights in the compute dtype, which the
+    layers cast them to at every use; bf16 halves the bytes)."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=_is_spec)
+    if dtype is not None:
+        leaves = [dataclasses.replace(s, dtype=dtype)
+                  if jnp.issubdtype(s.dtype, jnp.floating) else s
+                  for s in leaves]
     keys = jax.random.split(key, len(leaves))
     vals = [_init_leaf(s, k) for s, k in zip(leaves, keys)]
     return jax.tree.unflatten(treedef, vals)
@@ -211,7 +219,7 @@ def constrain(x: jnp.ndarray, axes: Sequence[Optional[str]],
     manual region (e.g. the pod-compressed step) the spec is resolved
     against the *context* AbstractMesh and Manual axes are excluded —
     only Auto axes may appear in a with_sharding_constraint there."""
-    if compat.manual_axis_sizes():
+    if manual_axis_sizes():
         # Inside a manual region: XLA's partitioner mishandles (and can
         # CHECK-crash on) sharding constraints under sdy.manual_computation;
         # rely on propagation from the operands' committed shardings.
@@ -224,12 +232,34 @@ def constrain(x: jnp.ndarray, axes: Sequence[Optional[str]],
 
 
 def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src.mesh import thread_resources
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh of an enclosing ``with mesh:`` block, or None.
+
+    ``jax.sharding.get_mesh()`` reports only meshes entered through
+    ``jax.set_mesh``; the training and dry-run paths enter theirs with
+    ``with mesh:``, which JAX records only in its thread resources."""
+    m = _jax_mesh.thread_resources.env.physical_mesh
+    return None if m.empty else m
+
+
+def manual_axis_sizes() -> dict:
+    """{axis name: size} for mesh axes bound *manual* in the current trace
+    (empty outside any ``jax.shard_map`` region)."""
+    am = jax.sharding.get_abstract_mesh()
+    return {a: am.shape[a] for a in am.manual_axes}
+
+
+def match_vma(x: jnp.ndarray, *refs: Any) -> jnp.ndarray:
+    """``x`` cast to vary over every manual mesh axis that some leaf of
+    ``refs`` varies over (a no-op outside ``jax.shard_map``).
+
+    A ``lax.scan`` carry must enter the loop with the varying axes the
+    body gives it back.  Under the sharded serve dispatches the hidden
+    state starts from replicated tokens but leaves each layer varying
+    over ``slots``, because it has attended the slot-sharded cache."""
+    want = frozenset().union(
+        *(jax.typeof(r).vma for r in jax.tree.leaves(refs)))
+    missing = tuple(sorted(want - jax.typeof(x).vma))
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def shardmap_mesh(mesh: Optional[Mesh]):
@@ -240,8 +270,8 @@ def shardmap_mesh(mesh: Optional[Mesh]):
     context mesh is an AbstractMesh whose axis names differ from the
     original Mesh; shard_map then requires the *context* mesh. Outside any
     region, fall back to the caller-provided concrete mesh."""
-    am = compat.get_abstract_mesh()
-    if am is not None and not am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
         return am
     return mesh
 

@@ -16,10 +16,9 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist import compat
 from repro.models import attention, mla, moe
 from repro.models.common import (ParamSpec, constrain, cross_entropy_loss,
-                                 rms_norm, shardmap_mesh)
+                                 match_vma, rms_norm, shardmap_mesh)
 from repro.models.common import scan as mscan
 
 __all__ = [
@@ -103,7 +102,7 @@ def vocab_parallel_embed(tokens: jnp.ndarray, table: jnp.ndarray,
         x = x * in_range[..., None].astype(x.dtype)
         return jax.lax.psum(x, "model")
 
-    return compat.shard_map(local, mesh=shardmap_mesh(mesh),
+    return jax.shard_map(local, mesh=shardmap_mesh(mesh),
                          axis_names=frozenset({"model"}),
                          in_specs=(P(), P("model", None)),
                          out_specs=P())(tokens, table)
@@ -250,6 +249,7 @@ def _decode_blocks(params: dict, state: Dict[str, jnp.ndarray],
         tree = (batch["parents"], batch["pos_off"], batch["nchain"])
     x = vocab_parallel_embed(batch["tokens"], params["embed"], mesh,
                              cfg.vocab, cfg.use_tp_shardmap).astype(cfg.dtype)
+    x = match_vma(x, batch, state)
 
     if cfg.attn_kind == "mla":
         quant = "ckv_scale" in state

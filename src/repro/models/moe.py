@@ -24,7 +24,6 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist import compat
 from repro.kernels import ops as kops
 from repro.models.common import ParamSpec, constrain, shardmap_mesh
 
@@ -208,7 +207,7 @@ def moe_ffn_ep(x: jnp.ndarray, p: dict, cfg: ModelConfig, mesh: Mesh,
 
     batch_spec = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes
                                                    else None)
-    out = compat.shard_map(
+    out = jax.shard_map(
         local_fn, mesh=shardmap_mesh(mesh),
         axis_names=frozenset(mesh.axis_names),
         in_specs=(P(batch_spec, ep_axis, None), P(batch_spec, ep_axis),
@@ -230,11 +229,11 @@ def moe_ffn_ep_psum(x: jnp.ndarray, p: dict, cfg: ModelConfig, mesh: Mesh,
     e_local = e // ep
 
     def local_fn(x_loc, router_w, w1, w3, w2):
-        # pvary: type the replicated tokens as varying over the expert axis.
+        # type the replicated tokens as varying over the expert axis.
         # XLA's partial-manual partitioner CHECK-crashes (CreateBinary on a
         # copy) when a replicated operand feeds this region at 256 devices;
         # with every operand varying it takes the well-tested path.
-        x_loc = compat.pvary(x_loc, ep_axis)
+        x_loc = jax.lax.pcast(x_loc, ep_axis, to="varying")
         bl, sl, d = x_loc.shape
         xt = x_loc.reshape(-1, d)
         t = xt.shape[0]
@@ -257,7 +256,7 @@ def moe_ffn_ep_psum(x: jnp.ndarray, p: dict, cfg: ModelConfig, mesh: Mesh,
         aux = jax.lax.pmean(aux, ep_axis)
         return y.reshape(bl, sl, d), aux
 
-    return compat.shard_map(
+    return jax.shard_map(
         local_fn, mesh=shardmap_mesh(mesh), axis_names=frozenset({ep_axis}),
         in_specs=(P(), P(None, ep_axis), P(ep_axis, None, None),
                   P(ep_axis, None, None), P(ep_axis, None, None)),

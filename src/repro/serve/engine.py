@@ -89,7 +89,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import compat
 from repro.models.common import ParamSpec, shape_structs
 from repro.models.registry import get_api
 from repro.models import quant_kv
@@ -572,7 +571,7 @@ class ServeEngine:
 
         def copy(state, src, dst):
             return cache.copy_page(state, self.pspecs, src[0], dst[0])
-        copy = compat.shard_map(
+        copy = jax.shard_map(
             copy, mesh=self.mesh_plan.mesh,
             in_specs=(self._spec_state, self._spec_lane, self._spec_lane),
             out_specs=self._spec_state)
@@ -590,7 +589,7 @@ class ServeEngine:
         def scrub(state):
             return cache.zero_page(state, self.pspecs, 0)
         if self.mesh_plan is not None:
-            scrub = compat.shard_map(
+            scrub = jax.shard_map(
                 scrub, mesh=self.mesh_plan.mesh,
                 in_specs=(self._spec_state,), out_specs=self._spec_state)
         return self._get("scrub", scrub, self._state_structs())
@@ -629,7 +628,7 @@ class ServeEngine:
                 nxt = sample_tokens(logits, temp[None], top_k[None],
                                     top_p[None], seed[None], sidx[None])
                 return nxt, logits, state
-            prefill = compat.shard_map(
+            prefill = jax.shard_map(
                 prefill, mesh=self.mesh_plan.mesh,
                 in_specs=(self._spec_rep, self._spec_state,
                           self._spec_rep, self._spec_lane,
@@ -700,7 +699,7 @@ class ServeEngine:
             # appears in the body, so a sharded decode step moves zero
             # cross-device bytes — each device advances only its own lanes
             lane = self._spec_lane
-            decode = compat.shard_map(
+            decode = jax.shard_map(
                 decode, mesh=self.mesh_plan.mesh,
                 in_specs=(self._spec_rep, self._spec_state, *(lane,) * 8),
                 out_specs=(lane, lane, self._spec_state))
@@ -758,7 +757,7 @@ class ServeEngine:
             extra = ()
         if self.mesh_plan is not None:
             lane = self._spec_lane
-            spec = compat.shard_map(
+            spec = jax.shard_map(
                 spec, mesh=self.mesh_plan.mesh,
                 in_specs=(self._spec_rep, self._spec_state, *(lane,) * 9),
                 out_specs=(lane, lane, self._spec_state))
@@ -845,7 +844,7 @@ class ServeEngine:
         if self.mesh_plan is not None:
             lane = self._spec_lane
             n_lanes = 12 if self.paged else 11
-            tree = compat.shard_map(
+            tree = jax.shard_map(
                 tree, mesh=self.mesh_plan.mesh,
                 in_specs=(self._spec_rep, self._spec_state,
                           *(lane,) * n_lanes),

@@ -24,11 +24,9 @@ could hold:
   once at engine build; an optional model axis for sharded weights can
   compose later without changing this plan's data axis.
 
-Decode dispatches run under ``shard_map``
-(through :mod:`repro.dist.compat` — minding the jax-0.4.37 GSPMD gates
-in ``docs/architecture.md``) with logits and sampled tokens kept
-``P("slots")``-sharded, so a decode step moves **zero cross-device
-traffic**: only admission/retire touch the host.
+Decode dispatches run under ``jax.shard_map`` with logits and sampled
+tokens kept ``P("slots")``-sharded, so a decode step moves **zero
+cross-device traffic**: only admission/retire touch the host.
 
 Correctness note: per-slot decode math is batch-independent (each lane
 attends only through its own page-table row), so a sharded engine's

@@ -12,7 +12,6 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.dist import compat
 from repro.dist.collectives import make_tree_mesh
 from repro.models.common import (ParamSpec, init_params, make_shardings,
                                  shape_structs)
@@ -163,13 +162,14 @@ def build_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
 
     def step(state, batch):
         def per_pod(params, err, batch):
-            # pvary: make params "varying over pod" so AD yields the PER-POD
+            # make params "varying over pod" so AD yields the PER-POD
             # partial gradient. Without it the transpose inserts an implicit
             # fp32 psum over the pod axis — the compressed reduction below
             # would then double-reduce (and the DCN bytes would already have
             # been spent).
             params = jax.tree.map(
-                lambda p: compat.pvary(p, tuple(sub_axes)), params)
+                lambda p: jax.lax.pcast(p, tuple(sub_axes), to="varying"),
+                params)
             err = jax.tree.map(lambda e: e[0], err)   # strip pod block axis
             loss, grads = vg_c(params, batch)
             grads, new_err = compression.compressed_psum_mean(
@@ -179,7 +179,7 @@ def build_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig,
             return loss, grads, new_err
 
         pod_first = P(sub_axes)
-        loss, grads, new_err = compat.shard_map(
+        loss, grads, new_err = jax.shard_map(
             per_pod,
             mesh=tmesh,
             axis_names=frozenset(sub_axes),

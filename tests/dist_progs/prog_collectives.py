@@ -11,7 +11,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.dist.collectives import (factor_radix4, make_tree_mesh,
                                     tree_psum, tree_reduce_scatter_gather)
 from repro.dist.plan import make_reduction_plan
-from repro.dist.compat import shard_map
 from repro.optim.compression import compressed_psum_mean
 
 assert len(jax.devices()) == 8
@@ -34,8 +33,8 @@ def tree_fn(xl):
 def flat_fn(xl):
     return jax.lax.psum(xl, sub)  # same axes, single fused reduction
 
-got = jax.jit(shard_map(tree_fn, mesh=tmesh, in_specs=P(sub),
-                        out_specs=P(sub)))(x)
+got = jax.jit(jax.shard_map(tree_fn, mesh=tmesh, in_specs=P(sub),
+                            out_specs=P(sub)))(x)
 want = jnp.broadcast_to(x.sum(axis=0, keepdims=True), x.shape)
 np.testing.assert_allclose(np.asarray(got), np.asarray(want))
 
@@ -45,8 +44,8 @@ v = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16)
 def rs_fn(xl):
     return tree_reduce_scatter_gather(xl[0], sub)[None]
 
-got2 = jax.jit(shard_map(rs_fn, mesh=tmesh, in_specs=P(sub),
-                         out_specs=P(sub)))(v)
+got2 = jax.jit(jax.shard_map(rs_fn, mesh=tmesh, in_specs=P(sub),
+                             out_specs=P(sub)))(v)
 np.testing.assert_allclose(np.asarray(got2),
                            np.broadcast_to(v.sum(0), (8, 16)))
 
@@ -54,8 +53,8 @@ np.testing.assert_allclose(np.asarray(got2),
 # by the 8-device tree), pointing the caller at tree_psum instead
 bad = jnp.ones((8, 13), jnp.float32)
 try:
-    jax.jit(shard_map(rs_fn, mesh=tmesh, in_specs=P(sub),
-                      out_specs=P(sub)))(bad)
+    jax.jit(jax.shard_map(rs_fn, mesh=tmesh, in_specs=P(sub),
+                          out_specs=P(sub)))(bad)
 except ValueError as e:
     assert "use tree_psum for unscatterable payloads" in str(e), e
 else:
@@ -72,10 +71,10 @@ xi = jnp.asarray(np.random.default_rng(1).integers(-128, 128, (8, 7)),
 def tree_int_fn(xl):
     return tree_psum(xl, sub, plan=plan8)
 
-got_i = jax.jit(shard_map(tree_int_fn, mesh=tmesh, in_specs=P(sub),
-                          out_specs=P(sub)))(xi)
-want_i = jax.jit(shard_map(flat_fn, mesh=tmesh, in_specs=P(sub),
-                           out_specs=P(sub)))(xi)
+got_i = jax.jit(jax.shard_map(tree_int_fn, mesh=tmesh, in_specs=P(sub),
+                              out_specs=P(sub)))(xi)
+want_i = jax.jit(jax.shard_map(flat_fn, mesh=tmesh, in_specs=P(sub),
+                               out_specs=P(sub)))(xi)
 assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
 assert np.array_equal(np.asarray(got_i),
                       np.broadcast_to(np.asarray(xi).sum(0), (8, 7)))
@@ -92,7 +91,7 @@ def comp_fn(g, e):
     mean, new_err = compressed_psum_mean(grads, errs, sub, 8)
     return mean["w"][None], new_err["w"][None]
 
-mean, new_err = jax.jit(shard_map(
+mean, new_err = jax.jit(jax.shard_map(
     comp_fn, mesh=tmesh, in_specs=(P(sub), P(sub)),
     out_specs=(P(sub), P(sub))))(g_int, err0)
 # integer grid payloads with shared scale: mean can carry tiny fp error only

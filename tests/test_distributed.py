@@ -45,3 +45,10 @@ def test_mesh_sharded_engine_churn_invariants():
     shard-resident, and no page-table row ever references a page outside
     its slot's shard block."""
     assert "OK shard churn" in _run("prog_shard_churn.py")
+
+
+def test_chip_smoke_sharded_body_bit_exact():
+    """chip_smoke.py --chips 4's body on 4 virtual devices: the
+    mesh_shards=4 engine's tokens and logits equal the single-device
+    engine's on the same seeded requests."""
+    assert "OK chip smoke sharded" in _run("prog_chip_smoke_sharded.py")

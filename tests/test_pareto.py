@@ -1,9 +1,5 @@
 """Property tests for the autotuner's Pareto-dominance utilities
-(:mod:`repro.tune.pareto`) — pure host logic, no jax.
-
-Runs under real ``hypothesis`` when installed, or the offline shim
-(``tests/_hyp.py``) registered by ``conftest.py`` otherwise.
-"""
+(:mod:`repro.tune.pareto`) — pure host logic, no jax."""
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st

@@ -1,0 +1,115 @@
+"""Compile the serve path for a described TPU v5e chip (none attached).
+
+The TPU compiler runs here against a ``v5e:2x2`` topology description:
+it refuses what the chip would refuse (tile alignment, VMEM overuse, a
+program that does not fit HBM) without spending chip time.  Covered: the
+``moa_reduce`` split-K combine at llama3.2-3b decode shapes, the flash
+attention kernel at a published-width prefill shape, and the engine's
+decode and verify dispatches at published widths with depth cut to two
+layers.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every pytest-xdist
+worker imports this file.  All such compiles stay in this one file.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.registry import get_config
+from repro.kernels import ops as kops
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.moa_reduce import moa_reduce_pallas
+from repro.models.common import init_params, shape_structs
+from repro.models.registry import get_api
+from repro.serve import EngineConfig, ServeEngine, cache
+
+#: HBM of one v5e chip (Google Cloud, "TPU v5e": 16 GB).
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    # the TPU library otherwise writes its logs under the system temp dir
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, structs, sharding):
+    placed = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        structs)
+    return jax.jit(fn).lower(*placed).compile()
+
+
+def _fits(compiled) -> bool:
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    return peak <= V5E_HBM_BYTES
+
+
+# (pages, rows, cols) operands of the split-K page combine at llama3.2-3b
+# widths (24 q heads x 128 head dim, 8 slots): the l and o partials of a
+# decode step and of a K=4 verify step over 16 pages (max_seq 2048), a
+# 7-page cache (a page count no multiple of 4, so the radix-4 tree pads
+# inside the kernel), a one-slot prefill chunk, and a 20-operand stack
+# the kernel splits into blocks of 16 (the masked remainder block).
+@pytest.mark.parametrize("shape", [
+    (16, 8, 24), (16, 8, 3072), (16, 8, 15360), (7, 8, 3072),
+    (16, 1, 24 * 64 * 128), (20, 256, 512),
+])
+def test_moa_reduce_compiles_for_v5e(one_chip, shape):
+    c = _compile(moa_reduce_pallas,
+                 (jax.ShapeDtypeStruct(shape, jnp.float32),), one_chip)
+    assert "tpu_custom_call" in c.as_text()
+    assert _fits(c)
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    """A 1024-token prefill at llama3.2-3b widths: 24 query heads over 8
+    KV heads of 128, bf16."""
+    q = jax.ShapeDtypeStruct((1, 1024, 24, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1024, 8, 128), jnp.bfloat16)
+    c = _compile(lambda q, k, v: flash_attention_pallas(q, k, v),
+                 (q, kv, kv), one_chip)
+    assert "tpu_custom_call" in c.as_text()
+    assert _fits(c)
+
+
+@pytest.mark.parametrize("dispatch", ["decode", "spec"])
+def test_engine_dispatch_compiles_for_v5e(one_chip, monkeypatch, dispatch):
+    """The engine's plain-decode and K=4 verify executables at published
+    widths, two layers deep, 8 slots x 2048 tokens of paged KV: the
+    split-K combine must lower to the Pallas kernel and the program must
+    fit one chip."""
+    cfg = dataclasses.replace(get_config("llama3.2-3b"), n_layers=2)
+    specs = get_api(cfg).param_specs(cfg)
+    params = jax.eval_shape(
+        lambda k: init_params(specs, k, dtype=cfg.dtype), jax.random.key(0))
+    # the kernel layer asks the default backend (the CPU here) whether it
+    # is on a TPU; the compile below targets one
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    monkeypatch.setattr(cache, "state_zeros", shape_structs)
+    eng = ServeEngine(cfg, params, config=EngineConfig(
+        max_slots=8, max_seq=2048, prefill_chunk=512, spec_k=4))
+    monkeypatch.setattr(
+        eng, "_get", lambda key, fn, *structs: _compile(fn, structs,
+                                                        one_chip))
+    c = eng._decode_exe() if dispatch == "decode" else eng._spec_exe()
+    assert "tpu_custom_call" in c.as_text()
+    assert _fits(c)
