@@ -76,9 +76,21 @@ All jitted entry points are compiled ahead-of-time from shape structs
 (``jit(f).lower(...).compile()``), so **reported timings never include
 compile time** — the engine times only executions of already-compiled
 functions.
+
+**Observability** — every phase of the serve loop is a
+``jax.profiler.TraceAnnotation`` span (``serve.step``, ``serve.admit``
+and its ``.plan`` / ``.prefill`` / ``.commit`` phases, ``serve.decode``
+and its ``.prepare`` / ``.device`` / ``.commit`` phases), so a profiler
+trace lays device idle time against what the host was doing; the host
+phases also add their seconds to ``stats`` (``admit_plan_s``,
+``decode_prepare_s``, ...).  Inside every executable the model call and
+the sampling run under ``jax.named_scope("model")`` and ``("sample")``,
+and each executable is named by its key (``jit_decode``,
+``jit_prefill_b<bucket>``).  See ``docs/serving.md`` "Observability".
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import time
@@ -308,6 +320,8 @@ class ServeEngine:
         self._chunk_ewma: Optional[float] = None
         self._step_ewma: Optional[float] = None
         self._tps_ewma: Optional[float] = None
+        #: perf_counter readbacks of first tokens in the current step()
+        self._first_reads: List[float] = []
         self.reset_stats()
 
     def _probe_reuse(self, ctx) -> int:
@@ -352,6 +366,16 @@ class ServeEngine:
             "session_reused_tokens": 0, "session_snapshot_drops": 0,
             # degrade-ladder counters (all 0 with degrade off)
             "degrade_steps": 0, "prefill_dispatches": 0,
+            # host seconds in the serve loop's phases around the timed
+            # prefill_s / decode_s windows (see _phase)
+            "admit_plan_s": 0.0, "admit_commit_s": 0.0,
+            "decode_prepare_s": 0.0, "decode_commit_s": 0.0,
+            # first tokens read back by admissions, and the seconds each
+            # waited from its readback to the end of its step()
+            "first_token_hold_s": 0.0, "first_tokens": 0,
+            # keys attended, summed over prefilled rows and over the live
+            # lanes of decode steps (pos + 1 each): the work's context
+            "prefill_context_tokens": 0, "decode_context_tokens": 0,
         }
         #: decode lane-steps each mesh shard advanced (index = shard);
         #: a single-device engine accumulates everything in shard 0
@@ -366,6 +390,20 @@ class ServeEngine:
         self._admit_times: Dict[str, Deque[float]] = {
             "hit": deque(maxlen=_LATENCY_WINDOW),
             "cold": deque(maxlen=_LATENCY_WINDOW)}
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, counter: Optional[str] = None):
+        """One phase of the serve loop: a profiler span ``name`` (on the
+        device trace's clock; about a microsecond with no profiler
+        session) that, given a ``counter``, also adds the phase's host
+        seconds to ``self.stats[counter]``."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(name):
+            try:
+                yield
+            finally:
+                if counter is not None:
+                    self.stats[counter] += time.perf_counter() - t0
 
     def stats_summary(self) -> Dict[str, float]:
         """Derived view of the counters: tok/s rates, mean occupancy,
@@ -526,8 +564,12 @@ class ServeEngine:
         return structs
 
     def _get(self, key, fn, *arg_structs):
-        """AOT-compile on first use; compile time never enters the timers."""
+        """AOT-compile on first use; compile time never enters the timers.
+        The executable's module is named by its key, so a trace tells the
+        prefill buckets apart: ``"decode"`` traces as ``jit_decode``,
+        ``("prefill", 512)`` as ``jit_prefill_b512``."""
         if key not in self._exe:
+            fn.__name__ = key if isinstance(key, str) else "%s_b%d" % key
             self._exe[key] = jax.jit(fn).lower(*arg_structs).compile()
         return self._exe[key]
 
@@ -620,13 +662,15 @@ class ServeEngine:
                 # slot's real row, every other shard an all-scratch row
                 # (their writes land on scratch, their sampled lane is
                 # discarded by the host)
-                logits, state = self.api.prefill_chunk(
-                    params, state,
-                    {"tokens": tokens, "index": start, "nvalid": nvalid,
-                     "pages": pages},
-                    self.cfg)
-                nxt = sample_tokens(logits, temp[None], top_k[None],
-                                    top_p[None], seed[None], sidx[None])
+                with jax.named_scope("model"):
+                    logits, state = self.api.prefill_chunk(
+                        params, state,
+                        {"tokens": tokens, "index": start, "nvalid": nvalid,
+                         "pages": pages},
+                        self.cfg)
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits, temp[None], top_k[None],
+                                        top_p[None], seed[None], sidx[None])
                 return nxt, logits, state
             prefill = jax.shard_map(
                 prefill, mesh=self.mesh_plan.mesh,
@@ -640,26 +684,30 @@ class ServeEngine:
         elif self.paged:
             def prefill(params, state, tokens, pages, start, nvalid,
                         temp, top_k, top_p, seed, sidx):
-                logits, state = self.api.prefill_chunk(
-                    params, state,
-                    {"tokens": tokens, "index": start, "nvalid": nvalid,
-                     "pages": pages[None]},
-                    self.cfg)
-                nxt = sample_tokens(logits, temp[None], top_k[None],
-                                    top_p[None], seed[None], sidx[None])
+                with jax.named_scope("model"):
+                    logits, state = self.api.prefill_chunk(
+                        params, state,
+                        {"tokens": tokens, "index": start, "nvalid": nvalid,
+                         "pages": pages[None]},
+                        self.cfg)
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits, temp[None], top_k[None],
+                                        top_p[None], seed[None], sidx[None])
                 return nxt, logits, state
             extra = jax.ShapeDtypeStruct((self.max_pages,), jnp.int32)
         else:
             def prefill(params, state, tokens, slot, start, nvalid,
                         temp, top_k, top_p, seed, sidx):
                 slot_state = cache.slot_slice(state, self.specs, slot)
-                logits, new_slot = self.api.prefill_chunk(
-                    params, slot_state,
-                    {"tokens": tokens, "index": start, "nvalid": nvalid},
-                    self.cfg)
+                with jax.named_scope("model"):
+                    logits, new_slot = self.api.prefill_chunk(
+                        params, slot_state,
+                        {"tokens": tokens, "index": start, "nvalid": nvalid},
+                        self.cfg)
                 state = cache.slot_update(state, self.specs, slot, new_slot)
-                nxt = sample_tokens(logits, temp[None], top_k[None],
-                                    top_p[None], seed[None], sidx[None])
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits, temp[None], top_k[None],
+                                        top_p[None], seed[None], sidx[None])
                 return nxt, logits, state
             extra = jax.ShapeDtypeStruct((), jnp.int32)
         i32, f32 = jnp.int32, jnp.float32
@@ -675,23 +723,28 @@ class ServeEngine:
         if self.paged:
             def decode(params, state, tokens, positions, pages,
                        temps, top_ks, top_ps, seeds, idxs):
-                logits, state = self.api.decode_step(
-                    params, state,
-                    {"tokens": tokens, "index": positions, "pages": pages},
-                    self.cfg)
-                nxt = sample_tokens(logits, temps, top_ks, top_ps, seeds,
-                                    idxs)
+                with jax.named_scope("model"):
+                    logits, state = self.api.decode_step(
+                        params, state,
+                        {"tokens": tokens, "index": positions,
+                         "pages": pages},
+                        self.cfg)
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits, temps, top_ks, top_ps,
+                                        seeds, idxs)
                 return nxt, logits, state
             extra = (self._sds((self.max_slots, self.max_pages), jnp.int32,
                                lane=True),)
         else:
             def decode(params, state, tokens, positions,
                        temps, top_ks, top_ps, seeds, idxs):
-                logits, state = self.api.decode_step(
-                    params, state, {"tokens": tokens, "index": positions},
-                    self.cfg)
-                nxt = sample_tokens(logits, temps, top_ks, top_ps, seeds,
-                                    idxs)
+                with jax.named_scope("model"):
+                    logits, state = self.api.decode_step(
+                        params, state,
+                        {"tokens": tokens, "index": positions}, self.cfg)
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(logits, temps, top_ks, top_ps,
+                                        seeds, idxs)
                 return nxt, logits, state
             extra = ()
         if self.mesh_plan is not None:
@@ -737,23 +790,29 @@ class ServeEngine:
         if self.paged:
             def spec(params, state, tokens, positions, pages, nspec,
                      temps, top_ks, top_ps, seeds, idxs):
-                logits, state = self.api.verify_chunk(
-                    params, state,
-                    {"tokens": tokens, "index": positions, "pages": pages,
-                     "nspec": nspec}, self.cfg)
-                return (sample_block(logits, temps, top_ks, top_ps, seeds,
-                                     idxs), logits, state)
+                with jax.named_scope("model"):
+                    logits, state = self.api.verify_chunk(
+                        params, state,
+                        {"tokens": tokens, "index": positions,
+                         "pages": pages, "nspec": nspec}, self.cfg)
+                with jax.named_scope("sample"):
+                    toks = sample_block(logits, temps, top_ks, top_ps,
+                                        seeds, idxs)
+                return toks, logits, state
             extra = (self._sds((self.max_slots, self.max_pages), jnp.int32,
                                lane=True),)
         else:
             def spec(params, state, tokens, positions, nspec,
                      temps, top_ks, top_ps, seeds, idxs):
-                logits, state = self.api.verify_chunk(
-                    params, state,
-                    {"tokens": tokens, "index": positions, "nspec": nspec},
-                    self.cfg)
-                return (sample_block(logits, temps, top_ks, top_ps, seeds,
-                                     idxs), logits, state)
+                with jax.named_scope("model"):
+                    logits, state = self.api.verify_chunk(
+                        params, state,
+                        {"tokens": tokens, "index": positions,
+                         "nspec": nspec}, self.cfg)
+                with jax.named_scope("sample"):
+                    toks = sample_block(logits, temps, top_ks, top_ps,
+                                        seeds, idxs)
+                return toks, logits, state
             extra = ()
         if self.mesh_plan is not None:
             lane = self._spec_lane
@@ -822,10 +881,13 @@ class ServeEngine:
                      "nchain": nchain, "nspec": nspec}
             if pages is not None:
                 batch["pages"] = pages
-            logits, head_top, state = self.api.verify_tree(
-                params, state, batch, self.cfg, head_topk=self.spec_branch)
-            toks = sample_block(logits, pos_off, nchain, temps, top_ks,
-                                top_ps, seeds, idxs)
+            with jax.named_scope("model"):
+                logits, head_top, state = self.api.verify_tree(
+                    params, state, batch, self.cfg,
+                    head_topk=self.spec_branch)
+            with jax.named_scope("sample"):
+                toks = sample_block(logits, pos_off, nchain, temps, top_ks,
+                                    top_ps, seeds, idxs)
             if not heads_on:
                 # stable output structure: a 1-element dummy when the
                 # drafter never reads head candidates
@@ -1337,337 +1399,358 @@ class ServeEngine:
         logits.  A paged admission that finds the pool exhausted — even
         after reclaiming LRU retired entries — is *deferred*: re-queued at
         the head of the pending queue, never dropped."""
-        sp = req.sampling or GREEDY
-        ctx = req.context
-        slot32 = jnp.asarray(slot, jnp.int32)
-        sh = self._slot_shard(slot)
+        if req.admit_t is None:
+            req.admit_t = self.scheduler.clock()
+        with self._phase("serve.admit.plan", "admit_plan_s"):
+            sp = req.sampling or GREEDY
+            ctx = req.context
+            slot32 = jnp.asarray(slot, jnp.int32)
+            sh = self._slot_shard(slot)
 
-        # ---- prefix-cache lookup: reuse the longest resident prefix
-        # (mesh-sharded: only same-shard matches — page sharing can never
-        # cross a shard boundary, the pages live in different pool blocks)
-        reuse, src, removed = 0, -1, False
-        if self.prefix is not None:
-            allowed = (None if self.mesh_plan is None
-                       else (lambda s: self._slot_shard(s) == sh))
-            match_len, match_slot = self.prefix.longest_match(
-                ctx, allowed=allowed)
-            match_len = min(match_len, len(ctx) - 1)   # keep >= 1 token to
-            if match_len >= self.min_prefix:           # prefill for logits
-                reuse, src = match_len, match_slot
-            # the slot's pages are about to be overwritten: its old entry
-            # must stop matching NOW (later admissions in this same step
-            # would otherwise copy half-overwritten pages)
-            removed = self.prefix.remove(slot)
+            # ---- prefix-cache lookup: reuse the longest resident prefix
+            # (mesh-sharded: only same-shard matches — page sharing can never
+            # cross a shard boundary, the pages live in different pool blocks)
+            reuse, src, removed = 0, -1, False
+            if self.prefix is not None:
+                allowed = (None if self.mesh_plan is None
+                           else (lambda s: self._slot_shard(s) == sh))
+                match_len, match_slot = self.prefix.longest_match(
+                    ctx, allowed=allowed)
+                match_len = min(match_len, len(ctx) - 1)   # keep >= 1 token to
+                if match_len >= self.min_prefix:           # prefill for logits
+                    reuse, src = match_len, match_slot
+                # the slot's pages are about to be overwritten: its old entry
+                # must stop matching NOW (later admissions in this same step
+                # would otherwise copy half-overwritten pages)
+                removed = self.prefix.remove(slot)
 
-        # ---- session snapshot: a returning conversation's accumulated
-        # history re-admits as shared pages even after every slot turned
-        # over (the trie only sees *resident* rows) — used when it covers
-        # more than the best trie match
-        sess_row = None
-        conv = getattr(req, "_conv_id", None)
-        if self.paged and conv is not None:
-            sess = self.sessions.get(conv)
-            if sess is not None and sess.row is not None:
-                s_reuse = min(sess.covered, len(ctx) - 1)
-                if self.mesh_plan is not None and \
-                        self.pool.shard_of(int(sess.row[0])) != sh:
-                    # the snapshot's pages live in another shard's block;
-                    # this admission must re-prefill (or use the trie)
-                    s_reuse = 0
-                if s_reuse >= self.min_prefix and s_reuse > reuse:
-                    reuse, src = s_reuse, -1
-                    sess_row = sess.row
+            # ---- session snapshot: a returning conversation's accumulated
+            # history re-admits as shared pages even after every slot turned
+            # over (the trie only sees *resident* rows) — used when it covers
+            # more than the best trie match
+            sess_row = None
+            conv = getattr(req, "_conv_id", None)
+            if self.paged and conv is not None:
+                sess = self.sessions.get(conv)
+                if sess is not None and sess.row is not None:
+                    s_reuse = min(sess.covered, len(ctx) - 1)
+                    if self.mesh_plan is not None and \
+                            self.pool.shard_of(int(sess.row[0])) != sh:
+                        # the snapshot's pages live in another shard's block;
+                        # this admission must re-prefill (or use the trie)
+                        s_reuse = 0
+                    if s_reuse >= self.min_prefix and s_reuse > reuse:
+                        reuse, src = s_reuse, -1
+                        sess_row = sess.row
 
-        # ---- plan the prefill pieces over the remaining context
-        # (the degrade ladder caps the chunk under overload)
-        chunk = self._effective_chunk()
-        pieces = []
-        pos = reuse
-        prefill_end = reuse
-        while pos < len(ctx):
-            piece = ctx[pos:pos + chunk]
-            cb = next(b for b in self.chunk_buckets if b >= len(piece))
-            # bucket padding writes (masked-off) cache positions
-            # [pos, pos+cb); past max_seq dynamic_update_slice would CLAMP
-            # the start and silently overwrite valid earlier positions.
-            # Shrink the tail bucket to the cache room instead (one extra
-            # compile per distinct tail size, only for near-capacity
-            # prompts).
-            cb = min(cb, self.max_seq - pos)
-            toks = np.zeros((1, cb), np.int32)
-            toks[0, :len(piece)] = piece
-            pieces.append((pos, len(piece), cb, self._put_rep(toks)))
-            prefill_end = max(prefill_end, pos + cb)
-            pos += len(piece)
+            # ---- plan the prefill pieces over the remaining context
+            # (the degrade ladder caps the chunk under overload)
+            chunk = self._effective_chunk()
+            pieces = []
+            pos = reuse
+            prefill_end = reuse
+            while pos < len(ctx):
+                piece = ctx[pos:pos + chunk]
+                cb = next(b for b in self.chunk_buckets if b >= len(piece))
+                # bucket padding writes (masked-off) cache positions
+                # [pos, pos+cb); past max_seq dynamic_update_slice would CLAMP
+                # the start and silently overwrite valid earlier positions.
+                # Shrink the tail bucket to the cache room instead (one extra
+                # compile per distinct tail size, only for near-capacity
+                # prompts).
+                cb = min(cb, self.max_seq - pos)
+                toks = np.zeros((1, cb), np.int32)
+                toks[0, :len(piece)] = piece
+                pieces.append((pos, len(piece), cb, self._put_rep(toks)))
+                prefill_end = max(prefill_end, pos + cb)
+                pos += len(piece)
 
-        # ---- bind physical pages (paged) — may defer on pool exhaustion
-        cow = None
-        if self.paged:
-            in_place = bool(reuse) and sess_row is None and src == slot
-            row_src = sess_row if sess_row is not None else (
-                self.table[src] if reuse and not in_place else None)
-            ok, cow = self._bind_pages(slot, row_src, reuse, prefill_end,
-                                       in_place=in_place)
-            if not ok:
-                if removed and src != slot:    # the entry is gone even
-                    self.stats["prefix_evictions"] += 1   # on deferral
-                self.stats["oom_deferred"] += 1
-                self.scheduler.evict(slot)     # head of queue: deferred,
-                if not self.scheduler.active and not self.pool.used_count:
-                    raise RuntimeError(        # not dropped
-                        f"page pool ({self.pool.num_pages - self.pool.shards}"
-                        f" pages of {self.page_size} tokens"
-                        + (f", {self.pool.shards} shard blocks"
-                           if self.pool.shards > 1 else "")
-                        + f") cannot hold a single request of "
-                        f"{len(ctx)} context tokens")
-                return []
-
-        # ---- admission committed: account the lookup + bytes moved
-        # (session-sourced reuse is tallied separately — the trie counters
-        # keep meaning "the trie found/missed it")
-        if sess_row is not None:
-            self.stats["session_hits"] += 1
-            self.stats["session_reused_tokens"] += reuse
-        if self.prefix is not None:
-            if reuse and sess_row is None:
-                self.stats["prefix_hits"] += 1
-                self.stats["prefix_reused_tokens"] += reuse
-            elif not reuse:
-                self.stats["prefix_misses"] += 1
-            if removed and src != slot:
-                self.stats["prefix_evictions"] += 1
-
-        row = None
-        if self.paged:
-            if self.mesh_plan is None:
-                row = jnp.asarray(self.table[slot])
-            else:
-                # (shards, max_pages) lane-sharded dispatch rows: the
-                # target shard gets the slot's localized row, every other
-                # shard an all-scratch row — their prefill runs on garbage
-                # the host discards, the target lane is bit-exact
-                rows = np.zeros((self.shards, self.max_pages), np.int32)
-                rows[sh] = self.mesh_plan.local_pages(self.table[slot])
-                row = self._put_lane(rows)
-        for start, nvalid, cb, toks in pieces:
-            self._ensure_warm(("prefill", cb), self._prefill_exe(cb),
-                              self.params, self.state, toks,
-                              row if self.paged else slot32,
-                              self._put_rep(jnp.asarray(start, jnp.int32)),
-                              self._put_rep(jnp.asarray(nvalid, jnp.int32)),
-                              self._put_rep(jnp.asarray(0.0, jnp.float32)),
-                              self._put_rep(jnp.asarray(0, jnp.int32)),
-                              self._put_rep(jnp.asarray(1.0, jnp.float32)),
-                              self._put_rep(jnp.asarray(0, jnp.int32)),
-                              self._put_rep(jnp.asarray(0, jnp.int32)))
-        if self.paged:
-            if cow is not None:
-                page_copy = self._page_copy_exe()
-                if self.mesh_plan is None:
-                    self._ensure_warm("page_copy", page_copy, self.state,
-                                      slot32, slot32)
-                else:
-                    lane0 = self._put_lane(np.zeros(self.shards, np.int32))
-                    self._ensure_warm("page_copy", page_copy, self.state,
-                                      lane0, lane0)
-        else:
-            reset = self._reset_exe()
-            self._ensure_warm("reset", reset, self.state, slot32)
-            if reuse and src != slot:
-                copy = self._copy_exe()
-                self._ensure_warm("copy", copy, self.state, slot32, slot32)
-        # the first prefill token continues the request's sample stream
-        temp = self._put_rep(jnp.asarray(sp.temperature, jnp.float32))
-        top_k = self._put_rep(jnp.asarray(sp.top_k, jnp.int32))
-        top_p = self._put_rep(jnp.asarray(sp.top_p, jnp.float32))
-        seed = self._put_rep(jnp.asarray(sp.seed, jnp.int32))
-        sidx = self._put_rep(jnp.asarray(len(req.generated), jnp.int32))
-
-        t0 = time.perf_counter()
-        if self.paged:
-            if cow is not None:
-                # copy-on-write: ONE boundary page, not the whole prefix
-                if self.mesh_plan is None:
-                    cow_args = (jnp.asarray(cow[0], jnp.int32),
-                                jnp.asarray(cow[1], jnp.int32))
-                else:
-                    # per-shard src/dst lanes of shard-local ids: only the
-                    # target shard copies, the rest self-copy scratch
-                    blk = self.mesh_plan.block
-                    src_v = np.zeros(self.shards, np.int32)
-                    dst_v = np.zeros(self.shards, np.int32)
-                    src_v[sh] = cow[0] % blk
-                    dst_v[sh] = cow[1] % blk
-                    cow_args = (self._put_lane(src_v), self._put_lane(dst_v))
-                self.state = page_copy(self.state, *cow_args)
-                self.stats["prefix_bytes_copied"] += self.page_bytes
-                self.stats["pages_cow"] += 1
-        elif reuse and src != slot:
-            self.state = copy(self.state, jnp.asarray(src, jnp.int32),
-                              slot32)
-            self.stats["prefix_bytes_copied"] += self.slot_bytes
-        elif not reuse:
-            self.state = reset(self.state, slot32)
-        # (contiguous reuse with src == slot: the pages are already there;
-        #  paged cold / shared-full-pages: zero bytes move at admission)
-        nxt = None
-        for start, nvalid, cb, toks in pieces:
-            nxt, _, self.state = self._prefill_exe(cb)(
-                self.params, self.state, toks,
-                row if self.paged else slot32,
-                self._put_rep(jnp.asarray(start, jnp.int32)),
-                self._put_rep(jnp.asarray(nvalid, jnp.int32)),
-                temp, top_k, top_p, seed, sidx)
-        nxt.block_until_ready()
-        dt = time.perf_counter() - t0
-        self.stats["prefill_s"] += dt
-        self.stats["prefill_tokens"] += len(ctx) - reuse
-        self.stats["prefill_dispatches"] += len(pieces)
-        self.stats["admissions"] += 1
-        if self.prefix is not None:
-            self.stats["hit_admit_s" if reuse else "cold_admit_s"] += dt
-            self._admit_times["hit" if reuse else "cold"].append(dt)
-        if self.paged:
-            # restore the all-zeros scratch invariant this admission's
-            # prefill broadcasts dirtied, BEFORE the next admission or
-            # decode reads scratch through masked lanes
-            self._scrub_scratch()
-        if not reuse:
-            # prefix-hit admissions time a page copy plus (at most) a tiny
-            # tail chunk — feeding that into the model would make a "chunk"
-            # look far cheaper than a full prefill dispatch; only cold
-            # admissions give an unbiased chunk cost
-            self._feed_cost_model(chunk_s=dt / max(1, len(pieces)))
-        # sharded prefill returns one sampled lane per shard — only the
-        # target shard's is real (sh == 0 single-device, where nxt is (1,))
-        self.scheduler.on_prefill(req, int(np.asarray(nxt)[sh]))
-        if self.drafter is not None:
-            # fresh speculative bookkeeping for the slot's new occupant:
-            # exactly one unmaterialized token (the first sample above), a
-            # cold suffix cache, and no accept/head history to inherit
-            self._spec_unwritten[slot] = 1
-            self._suffix_caches[slot] = self.drafter.make_cache()
-            self._head_preds.pop(slot, None)
-            self._slot_accept.pop(slot, None)
-            self._shape_age.pop(slot, None)
-            self._slot_tps.pop(slot, None)
-        if self.prefix is not None:
-            # the slot's pages now hold exactly ctx (the sampled first
-            # token is not written until the next decode step feeds it)
-            evicted = self.prefix.insert(slot, ctx)
+            # ---- bind physical pages (paged) — may defer on pool exhaustion
+            cow = None
             if self.paged:
-                self._release_trie_evicted(evicted)
-        if self.dedup is not None:
-            # content-dedup the full pages this admission finalized: any
-            # byte-identical resident page — wherever it sits in either
-            # sequence — replaces this row's fresh copy by reference
-            self._dedup_slot(slot, len(ctx))
-        if req.slot is None:                   # retired on its first token
-            self._session_retire(req, slot)
-            if self.paged and not self._row_reusable(slot):
-                self._release_row(slot)
-            return [req]
-        return []
+                in_place = bool(reuse) and sess_row is None and src == slot
+                row_src = sess_row if sess_row is not None else (
+                    self.table[src] if reuse and not in_place else None)
+                ok, cow = self._bind_pages(slot, row_src, reuse, prefill_end,
+                                           in_place=in_place)
+                if not ok:
+                    if removed and src != slot:    # the entry is gone even
+                        self.stats["prefix_evictions"] += 1   # on deferral
+                    self.stats["oom_deferred"] += 1
+                    self.scheduler.evict(slot)     # head of queue: deferred,
+                    if not self.scheduler.active and not self.pool.used_count:
+                        n_pages = self.pool.num_pages - self.pool.shards
+                        raise RuntimeError(        # not dropped
+                            f"page pool ({n_pages} pages of "
+                            f"{self.page_size} tokens"
+                            + (f", {self.pool.shards} shard blocks"
+                               if self.pool.shards > 1 else "")
+                            + f") cannot hold a single request of "
+                            f"{len(ctx)} context tokens")
+                    return []
+
+            # ---- admission committed: account the lookup + bytes moved
+            # (session-sourced reuse is tallied separately — the trie
+            # counters keep meaning "the trie found/missed it")
+            if sess_row is not None:
+                self.stats["session_hits"] += 1
+                self.stats["session_reused_tokens"] += reuse
+            if self.prefix is not None:
+                if reuse and sess_row is None:
+                    self.stats["prefix_hits"] += 1
+                    self.stats["prefix_reused_tokens"] += reuse
+                elif not reuse:
+                    self.stats["prefix_misses"] += 1
+                if removed and src != slot:
+                    self.stats["prefix_evictions"] += 1
+
+            row = None
+            if self.paged:
+                if self.mesh_plan is None:
+                    row = jnp.asarray(self.table[slot])
+                else:
+                    # (shards, max_pages) lane-sharded dispatch rows: the
+                    # target shard gets the slot's localized row, every other
+                    # shard an all-scratch row — their prefill runs on
+                    # garbage the host discards, the target lane is
+                    # bit-exact
+                    rows = np.zeros((self.shards, self.max_pages), np.int32)
+                    rows[sh] = self.mesh_plan.local_pages(self.table[slot])
+                    row = self._put_lane(rows)
+            for start, nvalid, cb, toks in pieces:
+                self._ensure_warm(("prefill", cb), self._prefill_exe(cb),
+                                  self.params, self.state, toks,
+                                  row if self.paged else slot32,
+                                  self._put_rep(jnp.asarray(start, jnp.int32)),
+                                  self._put_rep(
+                                      jnp.asarray(nvalid, jnp.int32)),
+                                  self._put_rep(jnp.asarray(0.0, jnp.float32)),
+                                  self._put_rep(jnp.asarray(0, jnp.int32)),
+                                  self._put_rep(jnp.asarray(1.0, jnp.float32)),
+                                  self._put_rep(jnp.asarray(0, jnp.int32)),
+                                  self._put_rep(jnp.asarray(0, jnp.int32)))
+            if self.paged:
+                if cow is not None:
+                    page_copy = self._page_copy_exe()
+                    if self.mesh_plan is None:
+                        self._ensure_warm("page_copy", page_copy, self.state,
+                                          slot32, slot32)
+                    else:
+                        lane0 = self._put_lane(np.zeros(self.shards, np.int32))
+                        self._ensure_warm("page_copy", page_copy, self.state,
+                                          lane0, lane0)
+            else:
+                reset = self._reset_exe()
+                self._ensure_warm("reset", reset, self.state, slot32)
+                if reuse and src != slot:
+                    copy = self._copy_exe()
+                    self._ensure_warm("copy", copy, self.state, slot32, slot32)
+            # the first prefill token continues the request's sample stream
+            temp = self._put_rep(jnp.asarray(sp.temperature, jnp.float32))
+            top_k = self._put_rep(jnp.asarray(sp.top_k, jnp.int32))
+            top_p = self._put_rep(jnp.asarray(sp.top_p, jnp.float32))
+            seed = self._put_rep(jnp.asarray(sp.seed, jnp.int32))
+            sidx = self._put_rep(jnp.asarray(len(req.generated), jnp.int32))
+
+        with self._phase("serve.admit.prefill"):
+            t0 = time.perf_counter()
+            if self.paged:
+                if cow is not None:
+                    # copy-on-write: ONE boundary page, not the whole prefix
+                    if self.mesh_plan is None:
+                        cow_args = (jnp.asarray(cow[0], jnp.int32),
+                                    jnp.asarray(cow[1], jnp.int32))
+                    else:
+                        # per-shard src/dst lanes of shard-local ids: only the
+                        # target shard copies, the rest self-copy scratch
+                        blk = self.mesh_plan.block
+                        src_v = np.zeros(self.shards, np.int32)
+                        dst_v = np.zeros(self.shards, np.int32)
+                        src_v[sh] = cow[0] % blk
+                        dst_v[sh] = cow[1] % blk
+                        cow_args = (self._put_lane(src_v),
+                                    self._put_lane(dst_v))
+                    self.state = page_copy(self.state, *cow_args)
+                    self.stats["prefix_bytes_copied"] += self.page_bytes
+                    self.stats["pages_cow"] += 1
+            elif reuse and src != slot:
+                self.state = copy(self.state, jnp.asarray(src, jnp.int32),
+                                  slot32)
+                self.stats["prefix_bytes_copied"] += self.slot_bytes
+            elif not reuse:
+                self.state = reset(self.state, slot32)
+            # (contiguous reuse with src == slot: the pages are already there;
+            #  paged cold / shared-full-pages: zero bytes move at admission)
+            nxt = None
+            for start, nvalid, cb, toks in pieces:
+                nxt, _, self.state = self._prefill_exe(cb)(
+                    self.params, self.state, toks,
+                    row if self.paged else slot32,
+                    self._put_rep(jnp.asarray(start, jnp.int32)),
+                    self._put_rep(jnp.asarray(nvalid, jnp.int32)),
+                    temp, top_k, top_p, seed, sidx)
+            nxt.block_until_ready()
+            dt = time.perf_counter() - t0
+        with self._phase("serve.admit.commit", "admit_commit_s"):
+            self.stats["prefill_s"] += dt
+            self.stats["prefill_tokens"] += len(ctx) - reuse
+            # row i of the prefilled span [reuse, len(ctx)) attends i + 1
+            self.stats["prefill_context_tokens"] += (
+                (reuse + 1 + len(ctx)) * (len(ctx) - reuse) // 2)
+            self.stats["prefill_dispatches"] += len(pieces)
+            self.stats["admissions"] += 1
+            if self.prefix is not None:
+                self.stats["hit_admit_s" if reuse else "cold_admit_s"] += dt
+                self._admit_times["hit" if reuse else "cold"].append(dt)
+            if self.paged:
+                # restore the all-zeros scratch invariant this admission's
+                # prefill broadcasts dirtied, BEFORE the next admission or
+                # decode reads scratch through masked lanes
+                self._scrub_scratch()
+            if not reuse:
+                # prefix-hit admissions time a page copy plus (at most) a
+                # tiny tail chunk — feeding that into the model would make a
+                # "chunk" look far cheaper than a full prefill dispatch;
+                # only cold admissions give an unbiased chunk cost
+                self._feed_cost_model(chunk_s=dt / max(1, len(pieces)))
+            # sharded prefill returns one sampled lane per shard — only the
+            # target shard's is real (sh == 0 single-device, where nxt is
+            # (1,)); a request's first token is held until step() returns
+            first = req.first_token_t is None
+            self.scheduler.on_prefill(req, int(np.asarray(nxt)[sh]))
+            if first:
+                self._first_reads.append(time.perf_counter())
+            if self.drafter is not None:
+                # fresh speculative bookkeeping for the slot's new occupant:
+                # exactly one unmaterialized token (the first sample above), a
+                # cold suffix cache, and no accept/head history to inherit
+                self._spec_unwritten[slot] = 1
+                self._suffix_caches[slot] = self.drafter.make_cache()
+                self._head_preds.pop(slot, None)
+                self._slot_accept.pop(slot, None)
+                self._shape_age.pop(slot, None)
+                self._slot_tps.pop(slot, None)
+            if self.prefix is not None:
+                # the slot's pages now hold exactly ctx (the sampled first
+                # token is not written until the next decode step feeds it)
+                evicted = self.prefix.insert(slot, ctx)
+                if self.paged:
+                    self._release_trie_evicted(evicted)
+            if self.dedup is not None:
+                # content-dedup the full pages this admission finalized: any
+                # byte-identical resident page — wherever it sits in either
+                # sequence — replaces this row's fresh copy by reference
+                self._dedup_slot(slot, len(ctx))
+            if req.slot is None:                   # retired on its first token
+                self._session_retire(req, slot)
+                if self.paged and not self._row_reusable(slot):
+                    self._release_row(slot)
+                return [req]
+            return []
 
     # ------------------------------------------------------------- step
     def _decode_once(self) -> List[Request]:
         """One batched decode step over every live slot (idle slots run the
         greedy lane and their outputs are discarded)."""
-        pages_extra = ()
-        if self.paged:
-            # lazily allocate each live slot's write page for this step; a
-            # slot that cannot get one even after reclaim is preempted
-            # back to the queue (deferred, not dropped)
-            for slot, req in list(self.scheduler.active.items()):
-                if not self._ensure_pages(slot, req.pos, req.pos + 1):
-                    self.evict(slot)
-                    self.stats["oom_deferred"] += 1
-            if not self.scheduler.active:
-                return []
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        positions = np.zeros((self.max_slots,), np.int32)
-        sps = [GREEDY] * self.max_slots
-        sidx = [0] * self.max_slots
-        for slot, req in self.scheduler.active.items():
-            tokens[slot, 0] = req.generated[-1]
-            positions[slot] = req.pos
-            sps[slot] = req.sampling or GREEDY
-            sidx[slot] = len(req.generated)
-        if self.paged:
-            # idle lanes point their whole page-table row at the scratch
-            # page: their unconditional (discarded) writes can never touch
-            # a retired-but-reusable slot's real pages
-            disp = np.zeros((self.max_slots, self.max_pages), np.int32)
-            for slot in self.scheduler.active:
-                disp[slot] = self.table[slot]
-            pages_extra = (self._put_lane(self._local_disp(disp)),)
-        elif self.prefix is not None:
-            # idle lanes run in the shared dispatch too, and their
-            # (discarded) token's KV is written unconditionally at
-            # positions[slot]; aim each idle write at the first cache
-            # position the trie does NOT index, so a retired slot's
-            # matchable prefix survives until the slot is actually reused
-            for slot in range(self.max_slots):
-                if slot in self.scheduler.active:
-                    continue
-                n = self.prefix.length(slot)
-                if n is None:
-                    continue
-                if n >= self.max_seq:   # pages full: no safe position left
-                    self.prefix.remove(slot)
-                    self.stats["prefix_evictions"] += 1
-                else:
-                    positions[slot] = n
-        temps, top_ks, top_ps, seeds, idxs = (
-            self._put_lane(a) for a in sampling_lanes(sps, sidx))
-        toks_d = self._put_lane(tokens)
-        pos_d = self._put_lane(positions)
-        exe = self._decode_exe()
-        self._ensure_warm("decode", exe, self.params, self.state,
-                          toks_d, pos_d, *pages_extra,
-                          temps, top_ks, top_ps, seeds, idxs)
-        occ = self.scheduler.occupancy
-        live = list(self.scheduler.active)
+        with self._phase("serve.decode.prepare", "decode_prepare_s"):
+            pages_extra = ()
+            if self.paged:
+                # lazily allocate each live slot's write page for this step; a
+                # slot that cannot get one even after reclaim is preempted
+                # back to the queue (deferred, not dropped)
+                for slot, req in list(self.scheduler.active.items()):
+                    if not self._ensure_pages(slot, req.pos, req.pos + 1):
+                        self.evict(slot)
+                        self.stats["oom_deferred"] += 1
+                if not self.scheduler.active:
+                    return []
+            tokens = np.zeros((self.max_slots, 1), np.int32)
+            positions = np.zeros((self.max_slots,), np.int32)
+            sps = [GREEDY] * self.max_slots
+            sidx = [0] * self.max_slots
+            for slot, req in self.scheduler.active.items():
+                tokens[slot, 0] = req.generated[-1]
+                positions[slot] = req.pos
+                sps[slot] = req.sampling or GREEDY
+                sidx[slot] = len(req.generated)
+            if self.paged:
+                # idle lanes point their whole page-table row at the scratch
+                # page: their unconditional (discarded) writes can never touch
+                # a retired-but-reusable slot's real pages
+                disp = np.zeros((self.max_slots, self.max_pages), np.int32)
+                for slot in self.scheduler.active:
+                    disp[slot] = self.table[slot]
+                pages_extra = (self._put_lane(self._local_disp(disp)),)
+            elif self.prefix is not None:
+                # idle lanes run in the shared dispatch too, and their
+                # (discarded) token's KV is written unconditionally at
+                # positions[slot]; aim each idle write at the first cache
+                # position the trie does NOT index, so a retired slot's
+                # matchable prefix survives until the slot is actually reused
+                for slot in range(self.max_slots):
+                    if slot in self.scheduler.active:
+                        continue
+                    n = self.prefix.length(slot)
+                    if n is None:
+                        continue
+                    if n >= self.max_seq:   # pages full: no safe position left
+                        self.prefix.remove(slot)
+                        self.stats["prefix_evictions"] += 1
+                    else:
+                        positions[slot] = n
+            temps, top_ks, top_ps, seeds, idxs = (
+                self._put_lane(a) for a in sampling_lanes(sps, sidx))
+            toks_d = self._put_lane(tokens)
+            pos_d = self._put_lane(positions)
+            exe = self._decode_exe()
+            self._ensure_warm("decode", exe, self.params, self.state,
+                              toks_d, pos_d, *pages_extra,
+                              temps, top_ks, top_ps, seeds, idxs)
+            occ = self.scheduler.occupancy
+            live = list(self.scheduler.active)
 
-        t0 = time.perf_counter()
-        nxt, lg, self.state = exe(self.params, self.state, toks_d, pos_d,
-                                  *pages_extra,
-                                  temps, top_ks, top_ps, seeds, idxs)
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        if self.trace_logits:
-            self.logit_trace.append(np.asarray(lg)[live])
-        self.stats["decode_s"] += dt
-        self.stats["decode_tokens"] += len(live)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_lane_steps"] += len(live)
-        self.stats["occupancy_sum"] += occ
-        for slot in live:
-            self._shard_lane_steps[self._slot_shard(slot)] += 1
-        self._step_times.append(dt)
-        self._feed_cost_model(step_s=dt, tokens_per_step=1.0)
-        if self.prefix is not None:
-            # this step wrote each live slot's fed token into its pages
+        with self._phase("serve.decode.device"):
+            t0 = time.perf_counter()
+            nxt, lg, self.state = exe(self.params, self.state, toks_d, pos_d,
+                                      *pages_extra,
+                                      temps, top_ks, top_ps, seeds, idxs)
+            nxt = np.asarray(nxt)
+            dt = time.perf_counter() - t0
+        with self._phase("serve.decode.commit", "decode_commit_s"):
+            if self.trace_logits:
+                self.logit_trace.append(np.asarray(lg)[live])
+            self.stats["decode_s"] += dt
+            self.stats["decode_tokens"] += len(live)
+            self.stats["decode_steps"] += 1
+            self.stats["decode_lane_steps"] += len(live)
+            self.stats["decode_context_tokens"] += (
+                int(positions[live].sum()) + len(live))
+            self.stats["occupancy_sum"] += occ
             for slot in live:
-                self.prefix.extend(slot, int(tokens[slot, 0]))
-        reqs = {s: self.scheduler.active[s] for s in live}
-        done = self.scheduler.on_decode({s: int(nxt[s]) for s in live})
-        for slot in live:
-            if slot not in self.scheduler.active:
-                # retiring session turns snapshot their page row (one
-                # pool ref per page) before any release can free it
-                self._session_retire(reqs[slot], slot)
-        if self.paged:
-            # free a retiring slot's pages the moment nothing can reuse
-            # them: no prefix cache at all, or its trie entry was LRU-
-            # evicted while the slot was live (keeping the row would
-            # strand it — reclaim only scans trie entries)
+                self._shard_lane_steps[self._slot_shard(slot)] += 1
+            self._step_times.append(dt)
+            self._feed_cost_model(step_s=dt, tokens_per_step=1.0)
+            if self.prefix is not None:
+                # this step wrote each live slot's fed token into its pages
+                for slot in live:
+                    self.prefix.extend(slot, int(tokens[slot, 0]))
+            reqs = {s: self.scheduler.active[s] for s in live}
+            done = self.scheduler.on_decode({s: int(nxt[s]) for s in live})
             for slot in live:
-                if slot not in self.scheduler.active and \
-                        not self._row_reusable(slot):
-                    self._release_row(slot)
-        return done
+                if slot not in self.scheduler.active:
+                    # retiring session turns snapshot their page row (one
+                    # pool ref per page) before any release can free it
+                    self._session_retire(reqs[slot], slot)
+            if self.paged:
+                # free a retiring slot's pages the moment nothing can reuse
+                # them: no prefix cache at all, or its trie entry was LRU-
+                # evicted while the slot was live (keeping the row would
+                # strand it — reclaim only scans trie entries)
+                for slot in live:
+                    if slot not in self.scheduler.active and \
+                            not self._row_reusable(slot):
+                        self._release_row(slot)
+            return done
 
     # ----------------------------------------------- speculative decode
     def _truncate_emitted(self, req: Request, emitted: List[int]
@@ -1725,134 +1808,139 @@ class ServeEngine:
         then rewind per-slot lengths and release any page advanced past
         the accepted point.  Idle lanes run with ``nspec == 0`` — every
         one of their cache writes is masked off."""
-        k = self.spec_k
-        drafts: Dict[int, List[int]] = {}
-        for slot, req in self.scheduler.active.items():
-            # a draft past the cache capacity or the generation budget
-            # could never be emitted — don't verify (or page) it
-            kd = min(k, self.max_seq - req.pos - 1, req.remaining - 1)
-            sc = self._suffix_caches.get(slot)
-            if kd <= 0:
-                drafts[slot] = []
-            elif sc is not None:
-                drafts[slot] = self.drafter.propose_cached(
-                    sc, req.context, kd)
-            else:
-                drafts[slot] = self.drafter.propose(req.context, kd)
-        if self.paged:
-            for slot, req in list(self.scheduler.active.items()):
-                end = req.pos + 1 + len(drafts[slot])
-                if not self._ensure_pages(slot, req.pos, end):
-                    # not even the draft-free step fits: defer, not drop
+        with self._phase("serve.decode.prepare", "decode_prepare_s"):
+            k = self.spec_k
+            drafts: Dict[int, List[int]] = {}
+            for slot, req in self.scheduler.active.items():
+                # a draft past the cache capacity or the generation budget
+                # could never be emitted — don't verify (or page) it
+                kd = min(k, self.max_seq - req.pos - 1, req.remaining - 1)
+                sc = self._suffix_caches.get(slot)
+                if kd <= 0:
                     drafts[slot] = []
-                    if not self._ensure_pages(slot, req.pos, req.pos + 1):
-                        self.evict(slot)
-                        self.stats["oom_deferred"] += 1
-            if not self.scheduler.active:
-                return []
-        b = self.max_slots
-        tokens = np.zeros((b, k + 1), np.int32)
-        positions = np.zeros((b,), np.int32)
-        nspec = np.zeros((b,), np.int32)     # idle lanes: writes masked
-        sps = [GREEDY] * b
-        sidx = [0] * b
-        for slot, req in self.scheduler.active.items():
-            d = drafts[slot]
-            tokens[slot, 0] = req.generated[-1]
-            if d:
-                tokens[slot, 1:1 + len(d)] = d
-            positions[slot] = req.pos
-            nspec[slot] = 1 + len(d)
-            sps[slot] = req.sampling or GREEDY
-            sidx[slot] = len(req.generated)
-        pages_extra = ()
-        if self.paged:
-            disp = np.zeros((b, self.max_pages), np.int32)
-            for slot in self.scheduler.active:
-                disp[slot] = self.table[slot]
-            pages_extra = (self._put_lane(self._local_disp(disp)),)
-        temps, top_ks, top_ps, seeds, idxs = (
-            self._put_lane(a) for a in sampling_lanes(sps, sidx))
-        toks_d = self._put_lane(tokens)
-        pos_d = self._put_lane(positions)
-        nspec_d = self._put_lane(nspec)
-        exe = self._spec_exe()
-        self._ensure_warm("spec", exe, self.params, self.state, toks_d,
-                          pos_d, *pages_extra, nspec_d, temps, top_ks,
-                          top_ps, seeds, idxs)
-        occ = self.scheduler.occupancy
-        live = list(self.scheduler.active)
+                elif sc is not None:
+                    drafts[slot] = self.drafter.propose_cached(
+                        sc, req.context, kd)
+                else:
+                    drafts[slot] = self.drafter.propose(req.context, kd)
+            if self.paged:
+                for slot, req in list(self.scheduler.active.items()):
+                    end = req.pos + 1 + len(drafts[slot])
+                    if not self._ensure_pages(slot, req.pos, end):
+                        # not even the draft-free step fits: defer, not drop
+                        drafts[slot] = []
+                        if not self._ensure_pages(slot, req.pos, req.pos + 1):
+                            self.evict(slot)
+                            self.stats["oom_deferred"] += 1
+                if not self.scheduler.active:
+                    return []
+            b = self.max_slots
+            tokens = np.zeros((b, k + 1), np.int32)
+            positions = np.zeros((b,), np.int32)
+            nspec = np.zeros((b,), np.int32)     # idle lanes: writes masked
+            sps = [GREEDY] * b
+            sidx = [0] * b
+            for slot, req in self.scheduler.active.items():
+                d = drafts[slot]
+                tokens[slot, 0] = req.generated[-1]
+                if d:
+                    tokens[slot, 1:1 + len(d)] = d
+                positions[slot] = req.pos
+                nspec[slot] = 1 + len(d)
+                sps[slot] = req.sampling or GREEDY
+                sidx[slot] = len(req.generated)
+            pages_extra = ()
+            if self.paged:
+                disp = np.zeros((b, self.max_pages), np.int32)
+                for slot in self.scheduler.active:
+                    disp[slot] = self.table[slot]
+                pages_extra = (self._put_lane(self._local_disp(disp)),)
+            temps, top_ks, top_ps, seeds, idxs = (
+                self._put_lane(a) for a in sampling_lanes(sps, sidx))
+            toks_d = self._put_lane(tokens)
+            pos_d = self._put_lane(positions)
+            nspec_d = self._put_lane(nspec)
+            exe = self._spec_exe()
+            self._ensure_warm("spec", exe, self.params, self.state, toks_d,
+                              pos_d, *pages_extra, nspec_d, temps, top_ks,
+                              top_ps, seeds, idxs)
+            occ = self.scheduler.occupancy
+            live = list(self.scheduler.active)
 
-        t0 = time.perf_counter()
-        nxt, lg, self.state = exe(self.params, self.state, toks_d, pos_d,
-                                  *pages_extra, nspec_d, temps, top_ks,
-                                  top_ps, seeds, idxs)
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        if self.trace_logits:
-            self.logit_trace.append(np.asarray(lg)[live])
+        with self._phase("serve.decode.device"):
+            t0 = time.perf_counter()
+            nxt, lg, self.state = exe(self.params, self.state, toks_d, pos_d,
+                                      *pages_extra, nspec_d, temps, top_ks,
+                                      top_ps, seeds, idxs)
+            nxt = np.asarray(nxt)
+            dt = time.perf_counter() - t0
+        with self._phase("serve.decode.commit", "decode_commit_s"):
+            if self.trace_logits:
+                self.logit_trace.append(np.asarray(lg)[live])
 
-        emitted: Dict[int, List[int]] = {}
-        n_emitted = 0
-        for slot in live:
-            req = self.scheduler.active[slot]
-            d = drafts[slot]
-            toks, accepted = accept_tokens(nxt[slot], d)
-            toks = self._truncate_emitted(req, toks)
-            emitted[slot] = toks
-            n_emitted += len(toks)
-            self.stats["spec_drafted"] += len(d)
-            self.stats["spec_accepted"] += accepted
-            if d:
-                self.stats["spec_lanes_drafted"] += 1
-                if accepted:
-                    self.stats["spec_lanes_hit"] += 1
-                # candidates tested: the accepted prefix plus the first
-                # mismatch (if the walk stopped inside the draft)
-                self._update_slot_accept(
-                    slot, "chain", accepted,
-                    accepted + (1 if accepted < len(d) else 0), 1.0)
-            p = self._slot_accept.get(slot, {}).get("chain")
-            if p is not None:
-                self._feed_slot_rate(slot, expected_tokens_chain(p, k))
-        self.stats["decode_s"] += dt
-        self.stats["decode_tokens"] += n_emitted
-        self.stats["decode_steps"] += 1
-        self.stats["spec_steps"] += 1
-        self.stats["decode_lane_steps"] += len(live)
-        self.stats["occupancy_sum"] += occ
-        for slot in live:
-            self._shard_lane_steps[self._slot_shard(slot)] += 1
-        self._step_times.append(dt)
-        self._feed_cost_model(step_s=dt,
-                              tokens_per_step=n_emitted / len(live))
-        if self.prefix is not None:
-            # the step materialized each slot's fed-and-kept tokens: the
-            # last sampled token plus its accepted draft prefix
+            emitted: Dict[int, List[int]] = {}
+            n_emitted = 0
             for slot in live:
-                fed = ([int(tokens[slot, 0])]
-                       + drafts[slot][:len(emitted[slot]) - 1])
-                for t in fed:
-                    self.prefix.extend(slot, t)
-        new_len = {slot: int(positions[slot]) + len(emitted[slot])
-                   for slot in live}
-        reqs = {s: self.scheduler.active[s] for s in live}
-        done = self.scheduler.on_decode_tokens(emitted)
-        if self.paged:
+                req = self.scheduler.active[slot]
+                d = drafts[slot]
+                toks, accepted = accept_tokens(nxt[slot], d)
+                toks = self._truncate_emitted(req, toks)
+                emitted[slot] = toks
+                n_emitted += len(toks)
+                self.stats["spec_drafted"] += len(d)
+                self.stats["spec_accepted"] += accepted
+                if d:
+                    self.stats["spec_lanes_drafted"] += 1
+                    if accepted:
+                        self.stats["spec_lanes_hit"] += 1
+                    # candidates tested: the accepted prefix plus the first
+                    # mismatch (if the walk stopped inside the draft)
+                    self._update_slot_accept(
+                        slot, "chain", accepted,
+                        accepted + (1 if accepted < len(d) else 0), 1.0)
+                p = self._slot_accept.get(slot, {}).get("chain")
+                if p is not None:
+                    self._feed_slot_rate(slot, expected_tokens_chain(p, k))
+            self.stats["decode_s"] += dt
+            self.stats["decode_tokens"] += n_emitted
+            self.stats["decode_steps"] += 1
+            self.stats["spec_steps"] += 1
+            self.stats["decode_lane_steps"] += len(live)
+            self.stats["decode_context_tokens"] += (
+                int(positions[live].sum()) + len(live))
+            self.stats["occupancy_sum"] += occ
             for slot in live:
-                # rewind: rejected-draft pages past the accepted frontier
-                self._rollback_pages(slot, new_len[slot])
-                if slot not in self.scheduler.active:
-                    # snapshot AFTER rollback: the row maps exactly the
-                    # accepted (materialized) positions
-                    self._session_retire(reqs[slot], slot)
-                    if not self._row_reusable(slot):
-                        self._release_row(slot)
-        else:
-            for slot in live:
-                if slot not in self.scheduler.active:
-                    self._session_retire(reqs[slot], slot)
-        return done
+                self._shard_lane_steps[self._slot_shard(slot)] += 1
+            self._step_times.append(dt)
+            self._feed_cost_model(step_s=dt,
+                                  tokens_per_step=n_emitted / len(live))
+            if self.prefix is not None:
+                # the step materialized each slot's fed-and-kept tokens: the
+                # last sampled token plus its accepted draft prefix
+                for slot in live:
+                    fed = ([int(tokens[slot, 0])]
+                           + drafts[slot][:len(emitted[slot]) - 1])
+                    for t in fed:
+                        self.prefix.extend(slot, t)
+            new_len = {slot: int(positions[slot]) + len(emitted[slot])
+                       for slot in live}
+            reqs = {s: self.scheduler.active[s] for s in live}
+            done = self.scheduler.on_decode_tokens(emitted)
+            if self.paged:
+                for slot in live:
+                    # rewind: rejected-draft pages past the accepted frontier
+                    self._rollback_pages(slot, new_len[slot])
+                    if slot not in self.scheduler.active:
+                        # snapshot AFTER rollback: the row maps exactly the
+                        # accepted (materialized) positions
+                        self._session_retire(reqs[slot], slot)
+                        if not self._row_reusable(slot):
+                            self._release_row(slot)
+            else:
+                for slot in live:
+                    if slot not in self.scheduler.active:
+                        self._session_retire(reqs[slot], slot)
+            return done
 
     def _tree_decode_once(self, draft: bool = True) -> List[Request]:
         """One tree-speculative decode step over every live slot.
@@ -1874,237 +1962,243 @@ class ServeEngine:
         Lemma-3 closed forms and picks a chain-``spec_k`` or
         tree-``(spec_branch, d)`` draft shape per step — both run inside
         the same compiled wide dispatch, so the reconfiguration is free."""
-        branch = self.spec_branch
-        heads = self.head_drafter
-        cw = self._tree_width()
-        trees: Dict[int, Optional[TreeDraft]] = {}
-        shapes: Dict[int, str] = {}
-        u_map: Dict[int, int] = {}
-        if self.paged:
-            # the chain part is already emitted — it cannot shrink, so a
-            # slot that cannot page it is evicted (deferred, not dropped);
-            # drafted rows need no pages (they only ever touch scratch)
-            for slot, req in list(self.scheduler.active.items()):
+        with self._phase("serve.decode.prepare", "decode_prepare_s"):
+            branch = self.spec_branch
+            heads = self.head_drafter
+            cw = self._tree_width()
+            trees: Dict[int, Optional[TreeDraft]] = {}
+            shapes: Dict[int, str] = {}
+            u_map: Dict[int, int] = {}
+            if self.paged:
+                # the chain part is already emitted — it cannot shrink, so a
+                # slot that cannot page it is evicted (deferred, not dropped);
+                # drafted rows need no pages (they only ever touch scratch)
+                for slot, req in list(self.scheduler.active.items()):
+                    u = self._spec_unwritten.get(slot, 1)
+                    index = req.pos + 1 - u
+                    if not self._ensure_pages(slot, index, index + u):
+                        self.evict(slot)
+                        self._spec_unwritten.pop(slot, None)
+                        self.stats["oom_deferred"] += 1
+                if not self.scheduler.active:
+                    return []
+
+            # ---- drafting + the per-slot reconfigurator decision
+            for slot, req in self.scheduler.active.items():
                 u = self._spec_unwritten.get(slot, 1)
+                u_map[slot] = u
                 index = req.pos + 1 - u
-                if not self._ensure_pages(slot, index, index + u):
-                    self.evict(slot)
-                    self._spec_unwritten.pop(slot, None)
-                    self.stats["oom_deferred"] += 1
-            if not self.scheduler.active:
-                return []
+                room = self.max_seq - index - u   # cache rows left for drafts
+                max_depth = min(req.remaining - 1, room)
+                nodes = min(self.spec_tree_nodes, room, cw - u)
+                tree: Optional[TreeDraft] = None
+                if draft and nodes > 0 and max_depth > 0:
+                    acc = self._slot_accept.get(slot, {})
+                    p_chain = acc.get("chain", _ACCEPT_PRIOR)
+                    p_tree = acc.get("tree", _ACCEPT_PRIOR)
+                    shape = "tree"
+                    kd = min(self.spec_k, room, max_depth, cw - u)
+                    if self.spec_mode == "auto":
+                        # both shapes run in the same wide dispatch: equal
+                        # step cost, so the decision is purely on expected
+                        # emitted tokens (Lemma 3's crossover), each shape
+                        # priced at its own drafter's accept estimate
+                        shape = pick_shape(p_chain, p_tree, kd, nodes, branch)
+                        other = "tree" if shape == "chain" else "chain"
+                        age = self._shape_age.setdefault(
+                            slot, {"chain": 0, "tree": 0})
+                        explore = age[other] >= _EXPLORE_EVERY
+                        if explore:
+                            shape = other
+                        age[shape] = 0
+                        age["tree" if shape == "chain" else "chain"] += 1
+                        self.stats[f"spec_shape_{shape}"] += 1
+                        rec = {"slot": slot, "accept_chain": round(p_chain, 4),
+                               "accept_tree": round(p_tree, 4), "shape": shape}
+                        if explore:
+                            rec["explore"] = True
+                        self._spec_decisions.append(rec)
+                    sc = self._suffix_caches.get(slot)
+                    if shape == "chain":
+                        d = (self.drafter.propose_cached(sc, req.context, kd)
+                             if sc is not None
+                             else self.drafter.propose(req.context, kd))
+                        tree = TreeDraft.chain(tuple(d)) if d else None
+                    elif heads is not None and slot in self._head_preds:
+                        tree = heads.propose_tree(self._head_preds[slot],
+                                                  nodes, branch, max_depth)
+                    elif sc is not None:
+                        # cap the drafted depth so the budget buys hedges: a
+                        # branch-wide fan per spine level costs `branch`
+                        # nodes/level (uncapped, the rank-0 spine would eat
+                        # the whole budget and the "tree" degenerates to a
+                        # chain) — the same nodes//branch shape the Lemma-3
+                        # expected-tokens model prices
+                        tree = self.tree_drafter.propose_tree(
+                            sc, req.context, nodes, branch,
+                            min(max_depth, max(1, nodes // branch)))
+                    if tree is not None and tree.n == 0:
+                        tree = None
+                    shapes[slot] = shape
+                trees[slot] = tree
 
-        # ---- drafting + the per-slot reconfigurator decision
-        for slot, req in self.scheduler.active.items():
-            u = self._spec_unwritten.get(slot, 1)
-            u_map[slot] = u
-            index = req.pos + 1 - u
-            room = self.max_seq - index - u   # cache rows left for drafts
-            max_depth = min(req.remaining - 1, room)
-            nodes = min(self.spec_tree_nodes, room, cw - u)
-            tree: Optional[TreeDraft] = None
-            if draft and nodes > 0 and max_depth > 0:
-                acc = self._slot_accept.get(slot, {})
-                p_chain = acc.get("chain", _ACCEPT_PRIOR)
-                p_tree = acc.get("tree", _ACCEPT_PRIOR)
-                shape = "tree"
-                kd = min(self.spec_k, room, max_depth, cw - u)
-                if self.spec_mode == "auto":
-                    # both shapes run in the same wide dispatch: equal
-                    # step cost, so the decision is purely on expected
-                    # emitted tokens (Lemma 3's crossover), each shape
-                    # priced at its own drafter's accept estimate
-                    shape = pick_shape(p_chain, p_tree, kd, nodes, branch)
-                    other = "tree" if shape == "chain" else "chain"
-                    age = self._shape_age.setdefault(
-                        slot, {"chain": 0, "tree": 0})
-                    explore = age[other] >= _EXPLORE_EVERY
-                    if explore:
-                        shape = other
-                    age[shape] = 0
-                    age["tree" if shape == "chain" else "chain"] += 1
-                    self.stats[f"spec_shape_{shape}"] += 1
-                    rec = {"slot": slot, "accept_chain": round(p_chain, 4),
-                           "accept_tree": round(p_tree, 4), "shape": shape}
-                    if explore:
-                        rec["explore"] = True
-                    self._spec_decisions.append(rec)
-                sc = self._suffix_caches.get(slot)
-                if shape == "chain":
-                    d = (self.drafter.propose_cached(sc, req.context, kd)
-                         if sc is not None
-                         else self.drafter.propose(req.context, kd))
-                    tree = TreeDraft.chain(tuple(d)) if d else None
-                elif heads is not None and slot in self._head_preds:
-                    tree = heads.propose_tree(self._head_preds[slot],
-                                              nodes, branch, max_depth)
-                elif sc is not None:
-                    # cap the drafted depth so the budget buys hedges: a
-                    # branch-wide fan per spine level costs `branch`
-                    # nodes/level (uncapped, the rank-0 spine would eat
-                    # the whole budget and the "tree" degenerates to a
-                    # chain) — the same nodes//branch shape the Lemma-3
-                    # expected-tokens model prices
-                    tree = self.tree_drafter.propose_tree(
-                        sc, req.context, nodes, branch,
-                        min(max_depth, max(1, nodes // branch)))
-                if tree is not None and tree.n == 0:
-                    tree = None
-                shapes[slot] = shape
-            trees[slot] = tree
+            # ---- assemble the (B, C) block
+            b = self.max_slots
+            tokens = np.zeros((b, cw), np.int32)
+            # padding rows parent themselves: never an ancestor of a valid row
+            parents = np.broadcast_to(np.arange(cw, dtype=np.int32),
+                                      (b, cw)).copy()
+            pos_off = np.zeros((b, cw), np.int32)
+            positions = np.zeros((b,), np.int32)
+            nchain = np.zeros((b,), np.int32)   # idle lanes: 0, writes masked
+            nspec = np.zeros((b,), np.int32)
+            sps = [GREEDY] * b
+            sidx = [0] * b
+            for slot, req in self.scheduler.active.items():
+                u = u_map[slot]
+                ctx = req.context
+                tokens[slot, :u] = ctx[len(ctx) - u:]
+                parents[slot, 0] = -1
+                if u > 1:
+                    parents[slot, 1:u] = np.arange(u - 1, dtype=np.int32)
+                pos_off[slot, :u] = np.arange(u, dtype=np.int32)
+                tree = trees[slot]
+                n = tree.n if tree is not None else 0
+                if n:
+                    tokens[slot, u:u + n] = tree.tokens
+                    parents[slot, u:u + n] = [u - 1 if p < 0 else u + p
+                                              for p in tree.parents]
+                    pos_off[slot, u:u + n] = [u - 1 + d for d in tree.depths]
+                positions[slot] = req.pos + 1 - u
+                nchain[slot] = u
+                nspec[slot] = u + n
+                sps[slot] = req.sampling or GREEDY
+                sidx[slot] = len(req.generated)
+            pages_extra = ()
+            if self.paged:
+                disp = np.zeros((b, self.max_pages), np.int32)
+                for slot in self.scheduler.active:
+                    disp[slot] = self.table[slot]
+                pages_extra = (self._put_lane(self._local_disp(disp)),)
+            temps, top_ks, top_ps, seeds, idxs = (
+                self._put_lane(a) for a in sampling_lanes(sps, sidx))
+            toks_d = self._put_lane(tokens)
+            pos_d = self._put_lane(positions)
+            par_d = self._put_lane(parents)
+            off_d = self._put_lane(pos_off)
+            nch_d = self._put_lane(nchain)
+            nsp_d = self._put_lane(nspec)
+            exe = self._tree_exe()
+            self._ensure_warm("tree", exe, self.params, self.state, toks_d,
+                              pos_d, *pages_extra, par_d, off_d, nch_d, nsp_d,
+                              temps, top_ks, top_ps, seeds, idxs)
+            occ = self.scheduler.occupancy
+            live = list(self.scheduler.active)
 
-        # ---- assemble the (B, C) block
-        b = self.max_slots
-        tokens = np.zeros((b, cw), np.int32)
-        # padding rows parent themselves: never an ancestor of a valid row
-        parents = np.broadcast_to(np.arange(cw, dtype=np.int32),
-                                  (b, cw)).copy()
-        pos_off = np.zeros((b, cw), np.int32)
-        positions = np.zeros((b,), np.int32)
-        nchain = np.zeros((b,), np.int32)   # idle lanes: 0, writes masked
-        nspec = np.zeros((b,), np.int32)
-        sps = [GREEDY] * b
-        sidx = [0] * b
-        for slot, req in self.scheduler.active.items():
-            u = u_map[slot]
-            ctx = req.context
-            tokens[slot, :u] = ctx[len(ctx) - u:]
-            parents[slot, 0] = -1
-            if u > 1:
-                parents[slot, 1:u] = np.arange(u - 1, dtype=np.int32)
-            pos_off[slot, :u] = np.arange(u, dtype=np.int32)
-            tree = trees[slot]
-            n = tree.n if tree is not None else 0
-            if n:
-                tokens[slot, u:u + n] = tree.tokens
-                parents[slot, u:u + n] = [u - 1 if p < 0 else u + p
-                                          for p in tree.parents]
-                pos_off[slot, u:u + n] = [u - 1 + d for d in tree.depths]
-            positions[slot] = req.pos + 1 - u
-            nchain[slot] = u
-            nspec[slot] = u + n
-            sps[slot] = req.sampling or GREEDY
-            sidx[slot] = len(req.generated)
-        pages_extra = ()
-        if self.paged:
-            disp = np.zeros((b, self.max_pages), np.int32)
-            for slot in self.scheduler.active:
-                disp[slot] = self.table[slot]
-            pages_extra = (self._put_lane(self._local_disp(disp)),)
-        temps, top_ks, top_ps, seeds, idxs = (
-            self._put_lane(a) for a in sampling_lanes(sps, sidx))
-        toks_d = self._put_lane(tokens)
-        pos_d = self._put_lane(positions)
-        par_d = self._put_lane(parents)
-        off_d = self._put_lane(pos_off)
-        nch_d = self._put_lane(nchain)
-        nsp_d = self._put_lane(nspec)
-        exe = self._tree_exe()
-        self._ensure_warm("tree", exe, self.params, self.state, toks_d,
-                          pos_d, *pages_extra, par_d, off_d, nch_d, nsp_d,
-                          temps, top_ks, top_ps, seeds, idxs)
-        occ = self.scheduler.occupancy
-        live = list(self.scheduler.active)
+        with self._phase("serve.decode.device"):
+            t0 = time.perf_counter()
+            nxt, head_top, lg, self.state = exe(
+                self.params, self.state, toks_d, pos_d, *pages_extra, par_d,
+                off_d, nch_d, nsp_d, temps, top_ks, top_ps, seeds, idxs)
+            nxt = np.asarray(nxt)
+            dt = time.perf_counter() - t0
+        with self._phase("serve.decode.commit", "decode_commit_s"):
+            if self.trace_logits:
+                self.logit_trace.append(np.asarray(lg)[live])
+            head_np = np.asarray(head_top) if heads is not None else None
 
-        t0 = time.perf_counter()
-        nxt, head_top, lg, self.state = exe(
-            self.params, self.state, toks_d, pos_d, *pages_extra, par_d,
-            off_d, nch_d, nsp_d, temps, top_ks, top_ps, seeds, idxs)
-        nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        if self.trace_logits:
-            self.logit_trace.append(np.asarray(lg)[live])
-        head_np = np.asarray(head_top) if heads is not None else None
-
-        # ---- longest accepted root-to-leaf path per slot
-        emitted: Dict[int, List[int]] = {}
-        n_emitted = 0
-        for slot in live:
-            req = self.scheduler.active[slot]
-            u = u_map[slot]
-            tree = trees[slot]
-            if tree is not None:
-                sampled = [int(nxt[slot, u - 1])] + [
-                    int(nxt[slot, u + i]) for i in range(tree.n)]
-                toks, path = accept_path(sampled, tree)
-            else:
-                toks, path = [int(nxt[slot, u - 1])], []
-            toks = self._truncate_emitted(req, toks)
-            emitted[slot] = toks
-            n_emitted += len(toks)
-            n = tree.n if tree is not None else 0
-            self.stats["spec_drafted"] += n
-            self.stats["spec_accepted"] += len(path)
-            if n:
-                self.stats["spec_lanes_drafted"] += 1
-                if path:
-                    self.stats["spec_lanes_hit"] += 1
-                # fold this step's outcome into the slot's accept EWMA:
-                # per accepted level the walk tested |children| candidates
-                # (plus the final failed level, if it had any to test)
-                kids: Dict[int, int] = {}
-                for par in tree.parents:
-                    kids[par] = kids.get(par, 0) + 1
-                levels = []
-                cur = -1
-                for node in path:
-                    levels.append(kids.get(cur, 0))
-                    cur = node
-                fail = 1 if kids.get(cur, 0) else 0
-                if fail:
-                    levels.append(kids[cur])
-                if levels:
-                    self._update_slot_accept(
-                        slot, shapes.get(slot, "tree"), len(path),
-                        len(path) + fail, sum(levels) / len(levels))
-            acc = self._slot_accept.get(slot, {})
-            rates = []
-            if "tree" in acc:
-                rates.append(expected_tokens_tree(
-                    acc["tree"], self.spec_tree_nodes, branch))
-            if "chain" in acc and self.spec_mode == "auto":
-                rates.append(expected_tokens_chain(acc["chain"],
-                                                   self.spec_k))
-            if rates:
-                # auto mode runs whichever shape prices better next step,
-                # so the scheduler sees the better of the two estimates
-                self._feed_slot_rate(slot, max(rates))
-            if head_np is not None:
-                # head candidates at the last ACCEPTED row seed the next
-                # step's tree (they predict the depths after its sample)
-                r_star = (u - 1 if len(toks) <= 1
-                          else u + path[len(toks) - 2])
-                self._head_preds[slot] = head_np[slot, r_star]
-
-        self.stats["decode_s"] += dt
-        self.stats["decode_tokens"] += n_emitted
-        self.stats["decode_steps"] += 1
-        self.stats["spec_steps"] += 1
-        self.stats["spec_tree_steps"] += 1
-        self.stats["decode_lane_steps"] += len(live)
-        self.stats["occupancy_sum"] += occ
-        for slot in live:
-            self._shard_lane_steps[self._slot_shard(slot)] += 1
-        self._step_times.append(dt)
-        self._feed_cost_model(step_s=dt,
-                              tokens_per_step=n_emitted / len(live))
-        if self.prefix is not None:
-            # this step materialized each live slot's chain part
+            # ---- longest accepted root-to-leaf path per slot
+            emitted: Dict[int, List[int]] = {}
+            n_emitted = 0
             for slot in live:
-                for t in tokens[slot, :u_map[slot]]:
-                    self.prefix.extend(slot, int(t))
-        reqs = {s: self.scheduler.active[s] for s in live}
-        done = self.scheduler.on_decode_tokens(emitted)
-        for slot in live:
-            # the accepted path is the next step's chain part; drafted
-            # rows only ever touched scratch, so there is NO page rollback
-            self._spec_unwritten[slot] = max(1, len(emitted[slot]))
-            if slot not in self.scheduler.active:
-                self._session_retire(reqs[slot], slot)
-                self._head_preds.pop(slot, None)
-                if self.paged and not self._row_reusable(slot):
-                    self._release_row(slot)
-        return done
+                req = self.scheduler.active[slot]
+                u = u_map[slot]
+                tree = trees[slot]
+                if tree is not None:
+                    sampled = [int(nxt[slot, u - 1])] + [
+                        int(nxt[slot, u + i]) for i in range(tree.n)]
+                    toks, path = accept_path(sampled, tree)
+                else:
+                    toks, path = [int(nxt[slot, u - 1])], []
+                toks = self._truncate_emitted(req, toks)
+                emitted[slot] = toks
+                n_emitted += len(toks)
+                n = tree.n if tree is not None else 0
+                self.stats["spec_drafted"] += n
+                self.stats["spec_accepted"] += len(path)
+                if n:
+                    self.stats["spec_lanes_drafted"] += 1
+                    if path:
+                        self.stats["spec_lanes_hit"] += 1
+                    # fold this step's outcome into the slot's accept EWMA:
+                    # per accepted level the walk tested |children| candidates
+                    # (plus the final failed level, if it had any to test)
+                    kids: Dict[int, int] = {}
+                    for par in tree.parents:
+                        kids[par] = kids.get(par, 0) + 1
+                    levels = []
+                    cur = -1
+                    for node in path:
+                        levels.append(kids.get(cur, 0))
+                        cur = node
+                    fail = 1 if kids.get(cur, 0) else 0
+                    if fail:
+                        levels.append(kids[cur])
+                    if levels:
+                        self._update_slot_accept(
+                            slot, shapes.get(slot, "tree"), len(path),
+                            len(path) + fail, sum(levels) / len(levels))
+                acc = self._slot_accept.get(slot, {})
+                rates = []
+                if "tree" in acc:
+                    rates.append(expected_tokens_tree(
+                        acc["tree"], self.spec_tree_nodes, branch))
+                if "chain" in acc and self.spec_mode == "auto":
+                    rates.append(expected_tokens_chain(acc["chain"],
+                                                       self.spec_k))
+                if rates:
+                    # auto mode runs whichever shape prices better next step,
+                    # so the scheduler sees the better of the two estimates
+                    self._feed_slot_rate(slot, max(rates))
+                if head_np is not None:
+                    # head candidates at the last ACCEPTED row seed the next
+                    # step's tree (they predict the depths after its sample)
+                    r_star = (u - 1 if len(toks) <= 1
+                              else u + path[len(toks) - 2])
+                    self._head_preds[slot] = head_np[slot, r_star]
+
+            self.stats["decode_s"] += dt
+            self.stats["decode_tokens"] += n_emitted
+            self.stats["decode_steps"] += 1
+            self.stats["spec_steps"] += 1
+            self.stats["spec_tree_steps"] += 1
+            self.stats["decode_lane_steps"] += len(live)
+            # positions + nchain: each live lane's req.pos + 1
+            self.stats["decode_context_tokens"] += int(
+                (positions + nchain)[live].sum())
+            self.stats["occupancy_sum"] += occ
+            for slot in live:
+                self._shard_lane_steps[self._slot_shard(slot)] += 1
+            self._step_times.append(dt)
+            self._feed_cost_model(step_s=dt,
+                                  tokens_per_step=n_emitted / len(live))
+            if self.prefix is not None:
+                # this step materialized each live slot's chain part
+                for slot in live:
+                    for t in tokens[slot, :u_map[slot]]:
+                        self.prefix.extend(slot, int(t))
+            reqs = {s: self.scheduler.active[s] for s in live}
+            done = self.scheduler.on_decode_tokens(emitted)
+            for slot in live:
+                # the accepted path is the next step's chain part; drafted
+                # rows only ever touched scratch, so there is NO page rollback
+                self._spec_unwritten[slot] = max(1, len(emitted[slot]))
+                if slot not in self.scheduler.active:
+                    self._session_retire(reqs[slot], slot)
+                    self._head_preds.pop(slot, None)
+                    if self.paged and not self._row_reusable(slot):
+                        self._release_row(slot)
+            return done
 
     def step(self) -> List[Request]:
         """One engine iteration: degrade-ladder observation (when
@@ -2115,30 +2209,42 @@ class ServeEngine:
         that finished during this iteration (including any the ladder shed
         — retired-with-reason, never silently dropped)."""
         finished: List[Request] = []
-        if self.ladder is not None:
-            level = self.ladder.observe(self.scheduler.slo_pressure())
-            if level:
-                self.stats["degrade_steps"] += 1
-            if level >= DegradeLadder.SHED:
-                finished += self.scheduler.shed_hopeless()
-        victim = self.scheduler.maybe_preempt()
-        if victim is not None:
-            self.evict(victim)
-            self.stats["preemptions"] += 1
-        for slot, req in self.scheduler.admissions():
-            finished += self._admit(slot, req)
-        if self.scheduler.active:
-            spec_on = self.spec_k and not (
-                self.ladder is not None
-                and self.ladder.level >= DegradeLadder.SPEC_OFF)
-            if self.spec_mode != "chain":
-                # tree/auto modes ALWAYS step through the tree dispatch:
-                # under SPEC_OFF it runs with zero drafted nodes, which
-                # still drains each slot's unmaterialized chain part
-                finished += self._tree_decode_once(draft=bool(spec_on))
-            else:
-                finished += (self._spec_decode_once() if spec_on
-                             else self._decode_once())
+        self._first_reads = []
+        with self._phase("serve.step"):
+            if self.ladder is not None:
+                level = self.ladder.observe(self.scheduler.slo_pressure())
+                if level:
+                    self.stats["degrade_steps"] += 1
+                if level >= DegradeLadder.SHED:
+                    finished += self.scheduler.shed_hopeless()
+            victim = self.scheduler.maybe_preempt()
+            if victim is not None:
+                self.evict(victim)
+                self.stats["preemptions"] += 1
+            for slot, req in self.scheduler.admissions():
+                with self._phase("serve.admit"):
+                    finished += self._admit(slot, req)
+            if self.scheduler.active:
+                spec_on = self.spec_k and not (
+                    self.ladder is not None
+                    and self.ladder.level >= DegradeLadder.SPEC_OFF)
+                with self._phase("serve.decode"):
+                    if self.spec_mode != "chain":
+                        # tree/auto modes ALWAYS step through the tree
+                        # dispatch: under SPEC_OFF it runs with zero
+                        # drafted nodes, which still drains each slot's
+                        # unmaterialized chain part
+                        finished += self._tree_decode_once(
+                            draft=bool(spec_on))
+                    else:
+                        finished += (self._spec_decode_once() if spec_on
+                                     else self._decode_once())
+        # first tokens this step's admissions read back reach the caller
+        # only now, after the decode step that followed them
+        end = time.perf_counter()
+        self.stats["first_token_hold_s"] += sum(
+            end - t for t in self._first_reads)
+        self.stats["first_tokens"] += len(self._first_reads)
         return finished
 
     # -------------------------------------------------------------- run

@@ -72,7 +72,11 @@ class Request:
     generated: List[int] = field(default_factory=list)
     slot: Optional[int] = None
     pos: int = 0                # tokens currently in the slot's cache
+    # scheduler-clock stamps: queued, first admission started, first
+    # token read back from its prefill, retired
     submit_t: Optional[float] = None
+    admit_t: Optional[float] = None
+    first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     slo_met: Optional[bool] = None
     #: why the scheduler shed this request instead of serving it (``None``
@@ -331,7 +335,10 @@ class Scheduler:
     # -------------------------------------------------------------- decode
     def on_prefill(self, req: Request, first_token: int) -> None:
         """Record ``req``'s prefill result: the cache holds its context,
-        plus ``first_token`` sampled from the prefill logits."""
+        plus ``first_token`` sampled from the prefill logits (the
+        request's first token, unless it is a re-admission)."""
+        if req.first_token_t is None:
+            req.first_token_t = self.clock()
         req.pos = len(req.context)
         req.generated.append(int(first_token))
         self._maybe_retire(req)

@@ -85,6 +85,24 @@ def _assert_invariants(eng):
     shed = [r for r in eng.scheduler.finished if r.shed_reason is not None]
     assert len(shed) == eng.scheduler.shed_count
     assert all(r.slo_met is False for r in shed)
+    _assert_request_clocks(eng)
+
+
+def _assert_request_clocks(eng):
+    """Each request's first token is counted once, by an admission, and
+    every request's scheduler-clock stamps come in lifecycle order (a
+    shed request may never have been admitted)."""
+    sched = eng.scheduler
+    reqs = list(sched.pending) + list(sched.active.values()) + sched.finished
+    firsts = sum(1 for r in reqs if r.first_token_t is not None)
+    assert eng.stats["first_tokens"] == firsts <= eng.stats["admissions"]
+    for r in reqs:
+        stamps = [t for t in (r.submit_t, r.admit_t, r.first_token_t,
+                              r.finish_t) if t is not None]
+        assert stamps == sorted(stamps), r.rid
+        assert r.first_token_t is None or r.admit_t is not None
+        if r.finish_t is not None and r.shed_reason is None:
+            assert r.first_token_t is not None
 
 
 @pytest.fixture(scope="module", params=CHURN_ARCHS)
@@ -124,24 +142,28 @@ def test_churn_conserves_every_serve_invariant(churn_engine, data):
     vocab = eng.cfg.vocab
     for _ in range(data.draw(st.integers(min_value=2, max_value=5))):
         op = data.draw(st.integers(min_value=0, max_value=6))
+        # every argument is drawn on every iteration, whatever the op and
+        # the shared engine's state, so a replay makes the same draws
+        shared = data.draw(st.booleans())
+        slo = data.draw(st.sampled_from([None, 50.0, 5000.0]))
+        max_new = data.draw(st.integers(min_value=2, max_value=6))
+        conv = eng._churn_convs[data.draw(st.integers(min_value=0,
+                                                      max_value=1))]
+        victim = data.draw(st.integers(min_value=0,
+                                       max_value=eng.max_slots - 1))
         if op == 0 and len(eng.scheduler.pending) < 4:
             # one-shot submit: half shared-prefix (trie/dedup hits), half
             # random (cold churn); occasionally with a tight virtual SLO
             # so overload pressure and shedding actually fire
-            if data.draw(st.integers(min_value=0, max_value=1)):
+            if shared:
                 tail = [int(t) for t in rng.integers(0, vocab, (3,))]
                 prompt = eng._churn_shared + tail
             else:
                 prompt = [int(t) for t in rng.integers(0, vocab, (10,))]
-            slo = [None, 50.0, 5000.0][data.draw(
-                st.integers(min_value=0, max_value=2))]
-            eng.submit(prompt, int(data.draw(
-                st.integers(min_value=2, max_value=6))), slo_ms=slo)
+            eng.submit(prompt, max_new, slo_ms=slo)
         elif op == 1 and len(eng.scheduler.pending) < 4:
             # session turn: histories grow across examples; start the
             # conversation over before it outgrows max_seq
-            conv = eng._churn_convs[data.draw(
-                st.integers(min_value=0, max_value=1))]
             sess = eng.sessions.get(conv)
             if sess is not None and len(sess.history) > 20:
                 eng.end_session(conv)
@@ -152,16 +174,13 @@ def test_churn_conserves_every_serve_invariant(churn_engine, data):
             eng.step()
         elif op == 3 and eng.scheduler.active:
             slots = sorted(eng.scheduler.active)
-            eng.evict(slots[data.draw(st.integers(
-                min_value=0, max_value=len(slots) - 1))])
+            eng.evict(slots[victim % len(slots)])
         elif op == 4:
-            conv = eng._churn_convs[data.draw(
-                st.integers(min_value=0, max_value=1))]
             eng.end_session(conv)
         elif op == 5:
             eng._churn_clock[0] += 1.0      # burst of virtual time: every
             eng.step()                      # tight-SLO request goes doomed
-        else:
+        elif op == 6:
             eng._churn_clock[0] += 0.01
             eng.run(max_steps=8)            # drain toward retirement
         _assert_invariants(eng)
@@ -202,19 +221,23 @@ def test_tree_churn_conserves_every_serve_invariant(tree_churn_engine,
     vocab = eng.cfg.vocab
     for _ in range(data.draw(st.integers(min_value=2, max_value=5))):
         op = data.draw(st.integers(min_value=0, max_value=4))
+        # every argument is drawn on every iteration (see above)
+        shared = data.draw(st.booleans())
+        max_new = data.draw(st.integers(min_value=2, max_value=6))
+        conv = eng._churn_convs[data.draw(st.integers(min_value=0,
+                                                      max_value=1))]
+        victim = data.draw(st.integers(min_value=0,
+                                       max_value=eng.max_slots - 1))
         if op == 0 and len(eng.scheduler.pending) < 4:
             # repetitive tails accept deep paths, random ones reject at
             # the root — both tree outcomes churn inside the walk
-            if data.draw(st.integers(min_value=0, max_value=1)):
+            if shared:
                 tail = [int(t) for t in rng.integers(0, vocab, (3,))]
                 prompt = eng._churn_shared + tail
             else:
                 prompt = [int(t) for t in rng.integers(0, vocab, (10,))]
-            eng.submit(prompt, int(data.draw(
-                st.integers(min_value=2, max_value=6))))
+            eng.submit(prompt, max_new)
         elif op == 1 and len(eng.scheduler.pending) < 4:
-            conv = eng._churn_convs[data.draw(
-                st.integers(min_value=0, max_value=1))]
             sess = eng.sessions.get(conv)
             if sess is not None and len(sess.history) > 20:
                 eng.end_session(conv)
@@ -225,9 +248,8 @@ def test_tree_churn_conserves_every_serve_invariant(tree_churn_engine,
             eng.step()
         elif op == 3 and eng.scheduler.active:
             slots = sorted(eng.scheduler.active)
-            eng.evict(slots[data.draw(st.integers(
-                min_value=0, max_value=len(slots) - 1))])
-        else:
+            eng.evict(slots[victim % len(slots)])
+        elif op == 4:
             eng._churn_clock[0] += 0.01
             eng.run(max_steps=8)
         _assert_invariants(eng)

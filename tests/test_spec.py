@@ -295,27 +295,47 @@ def test_spec_churn_conserves_refcounts(churn_engine, data):
     vocab = eng.cfg.vocab
     for _ in range(data.draw(st.integers(min_value=2, max_value=5))):
         op = data.draw(st.integers(min_value=0, max_value=3))
+        # every argument is drawn on every iteration, whatever the op and
+        # the shared engine's state, so a replay makes the same draws
+        shared = data.draw(st.booleans())
+        max_new = data.draw(st.integers(min_value=2, max_value=6))
+        victim = data.draw(st.integers(min_value=0,
+                                       max_value=eng.max_slots - 1))
         if op == 0 and len(eng.scheduler.pending) < 4:
             # submit: half the time extend the shared prefix (prefix-hit
             # admissions), otherwise a fresh random prompt (cold + trie
             # churn); repetitive tails make some drafts accept, random
             # ones make others reject (spec rollback)
-            if data.draw(st.integers(min_value=0, max_value=1)):
+            if shared:
                 tail = [int(t) for t in rng.integers(0, vocab, (3,))]
                 prompt = eng._churn_shared + tail
             else:
                 prompt = [int(t) for t in rng.integers(0, vocab, (10,))]
-            eng.submit(prompt, int(data.draw(
-                st.integers(min_value=2, max_value=6))))
+            eng.submit(prompt, max_new)
         elif op == 1:
             eng.step()
         elif op == 2 and eng.scheduler.active:
             slots = sorted(eng.scheduler.active)
-            eng.evict(slots[data.draw(st.integers(
-                min_value=0, max_value=len(slots) - 1))])
-        else:
+            eng.evict(slots[victim % len(slots)])
+        elif op == 3:
             eng.run(max_steps=8)                # drain toward retirement
         _assert_refcounts_conserved(eng)
+        _assert_request_clocks(eng)
+
+
+def _assert_request_clocks(eng):
+    """Each request's first token is counted once, by an admission, and
+    its scheduler-clock stamps come in lifecycle order."""
+    sched = eng.scheduler
+    reqs = list(sched.pending) + list(sched.active.values()) + sched.finished
+    firsts = sum(1 for r in reqs if r.first_token_t is not None)
+    assert eng.stats["first_tokens"] == firsts <= eng.stats["admissions"]
+    for r in reqs:
+        stamps = [t for t in (r.submit_t, r.admit_t, r.first_token_t,
+                              r.finish_t) if t is not None]
+        assert stamps == sorted(stamps), r.rid
+        assert r.first_token_t is None or r.admit_t is not None
+        assert r.finish_t is None or r.first_token_t is not None
 
 
 # ---------------------------------------------------------------------------
