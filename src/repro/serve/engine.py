@@ -376,6 +376,10 @@ class ServeEngine:
             # keys attended, summed over prefilled rows and over the live
             # lanes of decode steps (pos + 1 each): the work's context
             "prefill_context_tokens": 0, "decode_context_tokens": 0,
+            # sampling dispatches (prefill pieces, decode / spec / tree
+            # steps), and those whose every lane was greedy: they take
+            # sample_tokens' argmax-only branch
+            "sample_dispatches": 0, "sample_greedy_dispatches": 0,
         }
         #: decode lane-steps each mesh shard advanced (index = shard);
         #: a single-device engine accumulates everything in shard 0
@@ -390,6 +394,13 @@ class ServeEngine:
         self._admit_times: Dict[str, Deque[float]] = {
             "hit": deque(maxlen=_LATENCY_WINDOW),
             "cold": deque(maxlen=_LATENCY_WINDOW)}
+
+    def _count_sample(self, sps: Sequence[SamplingParams],
+                      dispatches: int = 1) -> None:
+        """Tally ``dispatches`` sampling dispatches over the lanes ``sps``."""
+        self.stats["sample_dispatches"] += dispatches
+        if all(p.is_greedy for p in sps):
+            self.stats["sample_greedy_dispatches"] += dispatches
 
     @contextlib.contextmanager
     def _phase(self, name: str, counter: Optional[str] = None):
@@ -1598,6 +1609,7 @@ class ServeEngine:
             self.stats["prefill_context_tokens"] += (
                 (reuse + 1 + len(ctx)) * (len(ctx) - reuse) // 2)
             self.stats["prefill_dispatches"] += len(pieces)
+            self._count_sample([sp], len(pieces))
             self.stats["admissions"] += 1
             if self.prefix is not None:
                 self.stats["hit_admit_s" if reuse else "cold_admit_s"] += dt
@@ -1722,6 +1734,7 @@ class ServeEngine:
             self.stats["decode_s"] += dt
             self.stats["decode_tokens"] += len(live)
             self.stats["decode_steps"] += 1
+            self._count_sample(sps)
             self.stats["decode_lane_steps"] += len(live)
             self.stats["decode_context_tokens"] += (
                 int(positions[live].sum()) + len(live))
@@ -1904,6 +1917,7 @@ class ServeEngine:
             self.stats["decode_s"] += dt
             self.stats["decode_tokens"] += n_emitted
             self.stats["decode_steps"] += 1
+            self._count_sample(sps)
             self.stats["spec_steps"] += 1
             self.stats["decode_lane_steps"] += len(live)
             self.stats["decode_context_tokens"] += (
@@ -2170,6 +2184,7 @@ class ServeEngine:
             self.stats["decode_s"] += dt
             self.stats["decode_tokens"] += n_emitted
             self.stats["decode_steps"] += 1
+            self._count_sample(sps)
             self.stats["spec_steps"] += 1
             self.stats["spec_tree_steps"] += 1
             self.stats["decode_lane_steps"] += len(live)

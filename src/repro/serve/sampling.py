@@ -11,8 +11,11 @@ restarts, slot assignments, eviction/re-admission and batch composition
 (given identical logits).
 
 ``temperature == 0`` is the greedy fast path: the returned token is exactly
-``argmax(logits)``, bit-for-bit the PR 2 engine's behaviour, so greedy
-serving is unaffected by the sampling plumbing.
+``argmax(logits)``, bit-for-bit the greedy engine's behaviour, so greedy
+serving is unaffected by the sampling plumbing.  A dispatch whose every
+lane is greedy runs the argmax alone: :func:`sample_tokens` branches once,
+on the device, on whether any lane samples, so the vocabulary-wide sort,
+softmax and cumsum run only in dispatches that need them.
 """
 from __future__ import annotations
 
@@ -98,10 +101,12 @@ def _sample_row(logits: jnp.ndarray, temp: jnp.ndarray, top_k: jnp.ndarray,
     greedy = jnp.argmax(logits).astype(jnp.int32)
 
     # descending sort once; temperature rescales monotonically, so the
-    # logit order and the scaled-prob order coincide
-    order = jnp.argsort(-logits)
-    scaled = logits[order] / jnp.maximum(temp, 1e-6)
+    # logit order and the scaled-prob order coincide.  The sort carries the
+    # values with the indices: negating its keys back gives logits[order]
+    # exactly, without a vocabulary-wide gather
     ranks = jnp.arange(vocab)
+    neg_sorted, order = jax.lax.sort((-logits, ranks), num_keys=1)
+    scaled = -neg_sorted / jnp.maximum(temp, 1e-6)
 
     kk = jnp.where(top_k <= 0, vocab, top_k)
     keep = ranks < kk
@@ -127,7 +132,9 @@ def sample_tokens(logits: jnp.ndarray, temps: jnp.ndarray,
     Args:
       logits: ``(B, V)`` float logits (one row per slot).
       temps: ``(B,)`` float temperatures; ``0`` = greedy argmax fast path
-        (bit-exact — the sampled branch is discarded by a ``where``).
+        (bit-exact).  When every lane is ``0`` the dispatch takes the
+        argmax alone; otherwise every lane runs the sampling path, whose
+        greedy lanes return the same argmax.
       top_ks: ``(B,)`` int top-k truncation per slot (``0`` disables).
       top_ps: ``(B,)`` float nucleus threshold per slot (``1.0`` disables).
       seeds: ``(B,)`` int per-request PRNG seeds.
@@ -138,4 +145,11 @@ def sample_tokens(logits: jnp.ndarray, temps: jnp.ndarray,
     Returns:
       ``(B,)`` int32 token ids.
     """
-    return jax.vmap(_sample_row)(logits, temps, top_ks, top_ps, seeds, idxs)
+    # one scalar branch outside the vmap (a batched predicate would turn
+    # the cond into a select that runs both sides); under shard_map each
+    # shard decides on its own lanes
+    return jax.lax.cond(
+        jnp.any(temps > 0.0),
+        lambda: jax.vmap(_sample_row)(logits, temps, top_ks, top_ps, seeds,
+                                      idxs),
+        lambda: jnp.argmax(logits, axis=-1).astype(jnp.int32))

@@ -2,6 +2,8 @@
 semantics, and restart determinism of the stateless per-request PRNG
 stream (same seed + same SamplingParams => identical tokens across
 engine rebuilds; temperature=0 => bit-exact with the greedy engine)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,6 +13,7 @@ from repro.configs.registry import get_config
 from repro.models.common import init_params
 from repro.models.registry import get_api
 from repro.serve import SamplingParams, ServeEngine, sample_tokens
+from repro.serve.sampling import _sample_row
 
 jax.config.update("jax_enable_x64", False)
 
@@ -88,6 +91,170 @@ def test_sampling_params_validation():
         SamplingParams(top_p=1.5)
     assert SamplingParams().is_greedy
     assert not SamplingParams(temperature=0.5).is_greedy
+
+
+# ---------------------------------------------------------------------------
+# the all-greedy branch
+# ---------------------------------------------------------------------------
+
+_sample_jit = jax.jit(sample_tokens)
+
+
+@pytest.mark.parametrize("vocab", [97, 151552])
+@pytest.mark.parametrize("b", [1, 8, 32])
+def test_all_greedy_batch_is_bitwise_argmax(b, vocab):
+    """Every lane greedy: the dispatch returns ``argmax`` bit for bit,
+    ties included (the lowest index wins)."""
+    rng = np.random.default_rng(vocab + b)
+    logits = rng.normal(size=(b, vocab)).astype(np.float32)
+    logits[0, [3, vocab - 2]] = logits[0].max() + 1.0     # a tied maximum
+    logits = jnp.asarray(logits)
+    toks = _sample_jit(logits, *_lanes(b, temperature=0.0))
+    assert toks.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(toks),
+                                  np.asarray(jnp.argmax(logits, axis=-1)))
+    assert int(toks[0]) == 3
+
+
+@pytest.mark.parametrize("idx", [0, 5])
+def test_mixed_batch_matches_per_row_sampler(idx):
+    """One lane samples (temperature 0.8, top-p 0.9), the rest are greedy:
+    the whole batch takes the sampling path, token for token what
+    ``_sample_row`` gives each row on its own."""
+    b, vocab = 8, 151552
+    rng = np.random.default_rng(8)
+    logits = jnp.asarray(rng.normal(size=(b, vocab)) * 3.0, jnp.float32)
+    temps, top_ks, top_ps, seeds, idxs = _lanes(b, temperature=0.0, idx=idx)
+    temps = temps.at[2].set(0.8)
+    top_ps = top_ps.at[2].set(0.9)
+    toks = np.asarray(_sample_jit(logits, temps, top_ks, top_ps, seeds,
+                                  idxs))
+    row = jax.jit(_sample_row)
+    ref = [int(row(logits[i], temps[i], top_ks[i], top_ps[i], seeds[i],
+                   idxs[i])) for i in range(b)]
+    np.testing.assert_array_equal(toks, ref)
+    greedy = np.asarray(jnp.argmax(logits, axis=-1))
+    np.testing.assert_array_equal(np.delete(toks, 2), np.delete(greedy, 2))
+
+
+_COMP = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_CALLS = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation"
+    r")=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def _computations(hlo_text):
+    """HLO computation name -> its body lines."""
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        m = _COMP.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.strip() != "}":
+            cur.append(line)
+    return comps
+
+
+def _reachable(comps, root):
+    """Body lines of ``root`` and of every computation it calls."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            lines.append(line)
+            for single, many in _CALLS.findall(line):
+                todo += [single] if single else [
+                    n.strip().lstrip("%") for n in many.split(",")]
+    return lines
+
+
+def test_compiled_sampler_branches_and_greedy_branch_has_no_sort():
+    """The dispatch compiles to one ``conditional`` on a scalar; its
+    greedy branch (branch 0: the predicate "any lane samples" is false)
+    holds no sort, the sampling branch does."""
+    b, vocab = 8, 1024
+    logits = jax.ShapeDtypeStruct((b, vocab), jnp.float32)
+    lanes = jax.eval_shape(lambda: _lanes(b))
+    text = _sample_jit.lower(logits, *lanes).compile().as_text()
+    cond = [line for line in text.splitlines() if " conditional(" in line]
+    assert len(cond) == 1, cond
+    many = re.search(r"branch_computations=\{([^}]*)\}", cond[0]).group(1)
+    branches = [n.strip().lstrip("%") for n in many.split(",")]
+    assert len(branches) == 2, cond[0]
+    comps = _computations(text)
+    greedy, sampled = (_reachable(comps, n) for n in branches)
+    assert not any(" sort(" in line for line in greedy)
+    assert any(" sort(" in line for line in sampled)
+    # the sort carries the sorted values: no gather of B x V elements
+    gathers = re.findall(r"= \w+\[([\d,]*)\]\S* gather\(", text)
+    assert gathers, "the sampled token's index is a gather"
+    assert all(np.prod([int(d) for d in dims.split(",") if d]) < b * vocab
+               for dims in gathers), gathers
+
+
+def _gather_row(logits, temp, top_k, top_p, seed, idx):
+    """The plain formulation: argsort, then gather the logits in that
+    order (the reference the sorted-values path must match bit for bit)."""
+    vocab = logits.shape[-1]
+    order = jnp.argsort(-logits)
+    scaled = logits[order] / jnp.maximum(temp, 1e-6)
+    keep = jnp.arange(vocab) < jnp.where(top_k <= 0, vocab, top_k)
+    probs = jax.nn.softmax(scaled)
+    keep &= (jnp.cumsum(probs) - probs) < top_p
+    keep = keep.at[0].set(True)
+    masked = jnp.where(keep, scaled, -jnp.inf)
+    key = jax.random.fold_in(jax.random.PRNGKey(seed), idx)
+    return order[jax.random.categorical(key, masked)].astype(jnp.int32)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sampling_path_matches_gather_reference(dtype):
+    """Every lane samples, over tied and untied logits: the tokens equal
+    the argsort-and-gather formulation's, lane for lane."""
+    b, vocab = 8, 4099
+    rng = np.random.default_rng(10)
+    x = rng.normal(size=(b, vocab)) * 3.0
+    x[0, :40] = x[0].max()          # a tied head
+    x[1] = np.round(x[1])           # ties everywhere
+    logits = jnp.asarray(x, dtype)
+    ref = jax.jit(jax.vmap(_gather_row))
+    for temp, top_k, top_p in ((0.7, 0, 0.9), (1.3, 20, 1.0), (0.2, 0, 0.5)):
+        lanes = _lanes(b, temperature=temp, top_k=top_k, top_p=top_p,
+                       seed=11, idx=3)
+        np.testing.assert_array_equal(
+            np.asarray(_sample_jit(logits, *lanes)),
+            np.asarray(ref(logits, *lanes)))
+
+
+def test_engine_counts_greedy_sample_dispatches():
+    """All-greedy traffic: every sampling dispatch (prefill pieces and
+    decode steps) is an all-greedy one.  One sampled request among them
+    makes at least one dispatch a sampling one."""
+    cfg = _cfg()
+    _, params = _params(cfg)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, cfg.vocab, (n,)).tolist() for n in (7, 12, 5)]
+
+    def serve(samplings):
+        eng = ServeEngine(cfg, params, max_slots=2, max_seq=32,
+                          prefill_chunk=8)
+        eng.warmup()
+        eng.reset_stats()
+        for prompt, sp in zip(prompts, samplings):
+            eng.submit(prompt, 4, sampling=sp)
+        eng.run()
+        return eng.stats
+
+    greedy = serve([None] * 3)
+    assert greedy["sample_dispatches"] >= (greedy["prefill_dispatches"]
+                                           + greedy["decode_steps"])
+    assert greedy["sample_greedy_dispatches"] == greedy["sample_dispatches"]
+    mixed = serve([None, SamplingParams(temperature=0.7, seed=3), None])
+    assert (mixed["sample_greedy_dispatches"]
+            <= mixed["sample_dispatches"] - 1)
 
 
 # ---------------------------------------------------------------------------

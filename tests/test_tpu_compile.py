@@ -4,9 +4,9 @@ The TPU compiler runs here against a ``v5e:2x2`` topology description:
 it refuses what the chip would refuse (tile alignment, VMEM overuse, a
 program that does not fit HBM) without spending chip time.  Covered: the
 ``moa_reduce`` split-K combine at llama3.2-3b decode shapes, the flash
-attention kernel at a published-width prefill shape, and the engine's
+attention kernel at a published-width prefill shape, the engine's
 decode and verify dispatches at published widths with depth cut to two
-layers.
+layers, and the sampler's all-greedy branch at published vocabularies.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every pytest-xdist
@@ -26,7 +26,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.moa_reduce import moa_reduce_pallas
 from repro.models.common import init_params, shape_structs
 from repro.models.registry import get_api
-from repro.serve import EngineConfig, ServeEngine, cache
+from repro.serve import EngineConfig, ServeEngine, cache, sample_tokens
 
 #: HBM of one v5e chip (Google Cloud, "TPU v5e": 16 GB).
 V5E_HBM_BYTES = 16 * 10**9
@@ -113,3 +113,24 @@ def test_engine_dispatch_compiles_for_v5e(one_chip, monkeypatch, dispatch):
     c = eng._decode_exe() if dispatch == "decode" else eng._spec_exe()
     assert "tpu_custom_call" in c.as_text()
     assert _fits(c)
+
+
+@pytest.mark.parametrize("vocab", [151552, 73448])
+def test_sampler_keeps_its_greedy_branch_for_v5e(one_chip, vocab):
+    """32 lanes over the GLM-4 and MiniCPM3 vocabularies: the chip's
+    compiler keeps the one scalar ``conditional``, and the vocabulary
+    sort sits only inside a branch, not in the entry computation that
+    every dispatch runs."""
+    b = 32
+    lane = lambda dt: jax.ShapeDtypeStruct((b,), dt)
+    c = _compile(sample_tokens,
+                 (jax.ShapeDtypeStruct((b, vocab), jnp.float32),
+                  lane(jnp.float32), lane(jnp.int32), lane(jnp.float32),
+                  lane(jnp.int32), lane(jnp.int32)), one_chip)
+    lines = c.as_text().splitlines()
+    assert sum(" conditional(" in line for line in lines) == 1
+    assert any(" sort(" in line for line in lines)
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith("ENTRY "))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    assert not any(" sort(" in line for line in lines[start:end])
