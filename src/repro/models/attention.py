@@ -189,7 +189,10 @@ def _project_qkv(x: jnp.ndarray, p: dict, cfg: ModelConfig
 
 def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     """Broadcast KV heads to the query-head count (keeps the sharded q-head
-    axis layout instead of a split reshape the partitioner can't follow)."""
+    axis layout instead of a split reshape the partitioner can't follow).
+    Used by the auto-partitioned paths, :func:`gqa_train` and
+    :func:`gqa_decode`; the paged split-K core (:func:`_splitk_attend`)
+    and :func:`gqa_decode_splitk` group the queries per KV head instead."""
     if n_rep == 1:
         return k
     return jnp.repeat(k, n_rep, axis=2)
@@ -509,6 +512,12 @@ def _splitk_attend(q: jnp.ndarray, k_view: jnp.ndarray, v_view: jnp.ndarray,
     q: (B, C, H, hd) roped queries; k_view/v_view: (B, Smax, Hkv, hd)
     contiguous *views* of the cache (dense slot rows or gathered pages —
     identical math either way); ``valid`` masks attendable positions.
+    The queries are grouped per KV head, ``(B, C, Hkv, rep, hd)``, and
+    each group is contracted against its one KV head in a single batched
+    dot over ``(b, g)``: the view is never repeated to the query-head
+    count.  Flattening ``(Hkv, rep)`` back gives the head order
+    ``h = g * rep + r`` (``_pad_heads`` pads inside each group), so the
+    partials are exactly those of a repeated-KV formulation.
     Each page contributes a partial (sum-exp, PV) accumulator under the
     global row max, and the page-axis combine is an explicit N-operand
     reduction routed through the same radix-4 tree plan
@@ -523,24 +532,28 @@ def _splitk_attend(q: jnp.ndarray, k_view: jnp.ndarray, v_view: jnp.ndarray,
     b, c = q.shape[0], q.shape[1]
     smax = k_view.shape[1]
     n_pages = smax // page
+    hkv = cfg.n_kv_heads
     pad = tp_head_pad(cfg)
     hq = cfg.n_heads + pad
-    q = _pad_heads(q, pad, cfg.n_kv_heads)
-    n_rep = hq // cfg.n_kv_heads
-    k = _repeat_kv(k_view.astype(q.dtype), n_rep)
-    v = _repeat_kv(v_view.astype(q.dtype), n_rep)
-    scores = jnp.einsum("bchd,bshd->bhcs", q, k) / jnp.sqrt(
+    rep = hq // hkv
+    q = _pad_heads(q, pad, hkv).reshape(b, c, hkv, rep, cfg.hd)
+    k = k_view.astype(q.dtype)
+    v = v_view.astype(q.dtype)
+    scores = jnp.einsum("bcgrd,bsgd->bgrcs", q, k) / jnp.sqrt(
         jnp.asarray(cfg.hd, jnp.float32)).astype(q.dtype)
-    scores = jnp.where(valid, scores.astype(jnp.float32), NEG_INF)
+    scores = jnp.where(valid[:, :, None], scores.astype(jnp.float32),
+                       NEG_INF)                              # (b,g,r,C,S)
 
     # split-K over pages: global row max, then per-page partial accumulators
-    m = jnp.max(scores, axis=-1, keepdims=True)              # (b,h,C,1)
-    p_ = jnp.exp(scores - m)                                 # (b,h,C,S)
+    m = jnp.max(scores, axis=-1, keepdims=True)              # (b,g,r,C,1)
+    p_ = jnp.exp(scores - m)                                 # (b,g,r,C,S)
     pp = p_.reshape(*p_.shape[:-1], n_pages, page)
-    l_pages = jnp.moveaxis(pp.sum(axis=-1), -1, 0)           # (n_pages,b,h,C)
-    vp = jnp.moveaxis(v.reshape(b, n_pages, page, hq, cfg.hd), 1, 0)
-    o_pages = jnp.einsum("bhcns,nbshd->nbhcd",
+    l_pages = jnp.moveaxis(pp.sum(axis=-1), -1, 0)         # (n_pages,b,g,r,C)
+    vp = v.reshape(b, n_pages, page, hkv, cfg.hd)
+    o_pages = jnp.einsum("bgrcns,bnsgd->nbgrcd",
                          pp.astype(q.dtype), vp)             # (n_pages,...)
+    l_pages = l_pages.reshape(n_pages, b, hq, c)
+    o_pages = o_pages.reshape(n_pages, b, hq, c, cfg.hd)
 
     plan = dist_plan.make_reduction_plan(n_pages)
     if kops.on_tpu():
@@ -552,7 +565,7 @@ def _splitk_attend(q: jnp.ndarray, k_view: jnp.ndarray, v_view: jnp.ndarray,
         o = radix4_tree_sum(o_pages.astype(jnp.float32), plan)
     out = (o / l[..., None]).astype(q.dtype)                 # (b,h,C,hd)
     out = jnp.moveaxis(out, 1, 2)                            # (b,C,h,hd)
-    out = _unpad_heads(out, pad, cfg.n_kv_heads)
+    out = _unpad_heads(out, pad, hkv)
     return out.reshape(b, c, cfg.n_heads * cfg.hd)
 
 

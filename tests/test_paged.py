@@ -158,6 +158,74 @@ def test_paged_engine_tokens_bitexact_vs_contiguous(arch_id):
     assert paged.stats["admissions"] == len(prompts)
 
 
+# ---------------------------------------------------------------------------
+# split-K core: grouped-query contraction == repeated-KV formulation
+# ---------------------------------------------------------------------------
+
+def _repeated_kv_reference(q, k, v, valid, hd):
+    """The repeated-KV formula in f32: each KV head broadcast to its
+    query group, one softmax over the whole view, then PV."""
+    rep = q.shape[2] // k.shape[2]
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    scores = jnp.einsum("bchd,bshd->bhcs", q, k) / np.sqrt(hd)
+    scores = jnp.where(valid, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhcs,bshd->bchd", probs, v)
+    return out.reshape(q.shape[0], q.shape[1], -1)
+
+
+# (rep, C, mask, dtype, padded): decode (C=1) and a K=4 verify block
+# (C=5) at MHA, llama3.2-3b's and GLM-4's group sizes, one tree block
+# under its ancestor mask, and one group padded as on a model axis that
+# does not divide the head count.  f32 is held to reassociation of the
+# page sums (1e-5).  bf16 rounds the scores, the probabilities fed to
+# PV and the output to an 8-bit mantissa, a relative error of ~2**-8 on
+# each, so it is held to 3e-2 on unit-scale values.
+SPLITK_CASES = [
+    (1, 1, "causal", jnp.float32, False),
+    (1, 5, "causal", jnp.float32, False),
+    (3, 1, "causal", jnp.float32, False),
+    (3, 5, "causal", jnp.float32, False),
+    (16, 1, "causal", jnp.float32, False),
+    (16, 5, "causal", jnp.float32, False),
+    (16, 5, "tree", jnp.float32, False),
+    (3, 5, "causal", jnp.float32, True),
+    (16, 1, "causal", jnp.bfloat16, False),
+    (16, 5, "tree", jnp.bfloat16, False),
+]
+
+
+@pytest.mark.parametrize("rep,c,mask,dtype,padded", SPLITK_CASES)
+def test_splitk_attend_grouped_matches_repeated_kv(monkeypatch, rep, c,
+                                                   mask, dtype, padded):
+    from repro.models import attention
+    b, hkv, hd, page, smax = 2, 2, 32, 16, 48      # 3 pages: tree pads
+    cfg = dataclasses.replace(get_config("llama3.2-3b"),
+                              n_heads=hkv * rep, n_kv_heads=hkv,
+                              head_dim=hd)
+    if padded:     # one zero query head appended inside each group
+        monkeypatch.setattr(attention, "tp_head_pad", lambda _: hkv)
+    kq, kk, kv = jax.random.split(jax.random.key(rep * 10 + c), 3)
+    q = jax.random.normal(kq, (b, c, hkv * rep, hd), dtype)
+    k = jax.random.normal(kk, (b, smax, hkv, hd), dtype)
+    v = jax.random.normal(kv, (b, smax, hkv, hd), dtype)
+    cur = jnp.array([3, 40], jnp.int32)             # one slot near the end
+    if mask == "causal":
+        pos = cur[:, None] + jnp.arange(c, dtype=jnp.int32)
+        valid = attention.causal_valid(pos, smax)
+    else:          # root, two children, one grandchild under each
+        parents = jnp.tile(jnp.array([[-1, 0, 0, 1, 2]], jnp.int32), (b, 1))
+        valid = attention.tree_valid(cur, parents,
+                                     jnp.full((b,), c, jnp.int32), smax)
+    got = attention._splitk_attend(q, k, v, valid, cfg, page)
+    want = _repeated_kv_reference(q, k, v, valid, hd)
+    assert got.shape == (b, c, hkv * rep * hd) and got.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=tol, atol=tol)
+
+
 @pytest.mark.parametrize("arch_id", PAGED_ARCHS)
 def test_paged_prefix_hit_shares_pages_zero_copy(arch_id):
     """Shared-prefix workload: hits share whole pages by reference (only

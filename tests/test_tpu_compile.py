@@ -6,14 +6,18 @@ program that does not fit HBM) without spending chip time.  Covered: the
 ``moa_reduce`` split-K combine at llama3.2-3b decode shapes, the flash
 attention kernel at a published-width prefill shape, the engine's
 decode and verify dispatches at published widths with depth cut to two
-layers, and the sampler's all-greedy branch at published vocabularies.
+layers (llama3.2-3b, and GLM-4-9B's 16-query-head groups, whose KV
+view must never be repeated), and the sampler's all-greedy branch at
+published vocabularies.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every pytest-xdist
 worker imports this file.  All such compiles stay in this one file.
 """
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -91,13 +95,38 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     assert _fits(c)
 
 
-@pytest.mark.parametrize("dispatch", ["decode", "spec"])
-def test_engine_dispatch_compiles_for_v5e(one_chip, monkeypatch, dispatch):
-    """The engine's plain-decode and K=4 verify executables at published
-    widths, two layers deep, 8 slots x 2048 tokens of paged KV: the
-    split-K combine must lower to the Pallas kernel and the program must
-    fit one chip."""
-    cfg = dataclasses.replace(get_config("llama3.2-3b"), n_layers=2)
+def _arrays_of(compiled, slots, n_elements):
+    """Instructions of ``compiled`` (fused ones included) whose output, or
+    an element of its tuple, is an array of ``n_elements`` elements with an
+    axis of ``slots`` (a per-slot array, not a weight of the same size)."""
+    hits = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r" = (.*?) [a-z][\w.-]*\(", line)
+        if m is None:
+            continue
+        for dims in re.findall(r"[a-z]\w*\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            if slots in dims and math.prod(dims) == n_elements:
+                hits.append(line.strip())
+    return hits
+
+
+# (config, slots, page, dispatch): llama3.2-3b's plain-decode and K=4
+# verify executables, and GLM-4-9B's decode as the chat cell runs it
+# (32 query heads over 2 KV heads, 32 slots, 128-token pages).
+@pytest.mark.parametrize("arch_id,slots,page,dispatch", [
+    pytest.param("llama3.2-3b", 8, None, "decode", id="decode"),
+    pytest.param("llama3.2-3b", 8, None, "spec", id="spec"),
+    pytest.param("glm4-9b", 32, 128, "decode", id="glm4-9b-decode"),
+])
+def test_engine_dispatch_compiles_for_v5e(one_chip, monkeypatch, arch_id,
+                                          slots, page, dispatch):
+    """The engine's decode (and verify) executables at published widths,
+    two layers deep, ``slots`` x 2048 tokens of paged KV: the split-K
+    combine must lower to the Pallas kernel, the program must fit one
+    chip, and no instruction may hold the KV view repeated to the
+    query-head count (the split-K core groups the queries per KV head)."""
+    cfg = dataclasses.replace(get_config(arch_id), n_layers=2)
     specs = get_api(cfg).param_specs(cfg)
     params = jax.eval_shape(
         lambda k: init_params(specs, k, dtype=cfg.dtype), jax.random.key(0))
@@ -106,13 +135,18 @@ def test_engine_dispatch_compiles_for_v5e(one_chip, monkeypatch, dispatch):
     monkeypatch.setattr(kops, "on_tpu", lambda: True)
     monkeypatch.setattr(cache, "state_zeros", shape_structs)
     eng = ServeEngine(cfg, params, config=EngineConfig(
-        max_slots=8, max_seq=2048, prefill_chunk=512, spec_k=4))
+        max_slots=slots, max_seq=2048, prefill_chunk=512, page_size=page,
+        spec_k=4))
+    assert eng.paged
     monkeypatch.setattr(
         eng, "_get", lambda key, fn, *structs: _compile(fn, structs,
                                                         one_chip))
     c = eng._decode_exe() if dispatch == "decode" else eng._spec_exe()
     assert "tpu_custom_call" in c.as_text()
     assert _fits(c)
+    # repeating the K and V views to the query-head count would hold
+    # (slots, 2048, n_heads, hd) arrays; the grouped contraction holds none
+    assert _arrays_of(c, slots, slots * 2048 * cfg.n_heads * cfg.hd) == []
 
 
 @pytest.mark.parametrize("vocab", [151552, 73448])
